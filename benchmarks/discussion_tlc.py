@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 from repro.core import tlc
 from repro.flash import TimingModel
 
@@ -51,4 +52,5 @@ def main(quick: bool = True) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -27,6 +27,7 @@ import numpy as np
 
 from benchmarks.common import emit, write_json
 from repro.api import ComputeSession
+from repro.compile_cache import enable_compile_cache
 from repro.flash.geometry import SSDConfig
 
 PE_POINTS = (1_000, 5_000, 10_000)
@@ -123,6 +124,7 @@ def main(quick: bool = True, faults: bool = True) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", default=True)
     ap.add_argument("--full", dest="quick", action="store_false")

@@ -21,6 +21,7 @@ import numpy as np
 
 from benchmarks.common import emit, write_json
 from repro.api import ComputeSession
+from repro.compile_cache import enable_compile_cache
 from repro.flash import (bitmap_index, image_encryption, image_segmentation,
                          speedup_table)
 from repro.flash.geometry import SSDConfig
@@ -87,6 +88,7 @@ def main(quick: bool = True, trace: "str | None" = None,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--trace", nargs="?", const="trace_fig10.json",
                     default=None, metavar="OUT_JSON",

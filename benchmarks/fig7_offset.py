@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 from repro.core import mcflash, sensing, vth_model
 
 
@@ -49,4 +50,5 @@ def main(quick: bool = True) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -18,6 +18,7 @@ import numpy as np
 
 from benchmarks.common import emit, timeit, write_json
 from repro.api import ComputeSession, PallasBackend, PlanCache, SimBackend
+from repro.compile_cache import enable_compile_cache
 from repro.core.vth_model import get_chip_model
 from repro.flash.geometry import SSDConfig
 
@@ -170,6 +171,7 @@ def main(quick: bool = True, trace: "str | None" = None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", default=True,
                     help="small shapes (default; CI smoke mode)")

@@ -12,6 +12,7 @@ import traceback
 from benchmarks import (discussion_tlc, fig6_retention, fig7_offset,
                         fig8_latency_energy, fig9_system, fig10_apps,
                         kernel_throughput, table1_ops, table2_rber)
+from repro.compile_cache import enable_compile_cache
 
 MODULES = (
     ("table1_ops", table1_ops),
@@ -47,4 +48,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

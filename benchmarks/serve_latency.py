@@ -24,6 +24,7 @@ import numpy as np
 
 from benchmarks.common import emit, write_json
 from repro.api import ComputeSession
+from repro.compile_cache import enable_compile_cache
 from repro.flash.geometry import SSDConfig
 from repro.serve import QueryEngine, SLOConfig
 
@@ -154,6 +155,7 @@ def main(quick: bool = True, trace: "str | None" = None,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", default=True,
                     help="small shapes (default; CI smoke mode)")

@@ -15,6 +15,7 @@ import numpy as np
 
 from benchmarks.common import emit, timeit, write_json
 from repro.api import ComputeSession
+from repro.compile_cache import enable_compile_cache
 from repro.core import encoding
 
 
@@ -158,6 +159,7 @@ def main(quick: bool = True, trace: "str | None" = None,
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true", default=True)
     ap.add_argument("--full", dest="quick", action="store_false")

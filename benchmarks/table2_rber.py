@@ -4,6 +4,7 @@ from __future__ import annotations
 import time
 
 from benchmarks.common import emit
+from repro.compile_cache import enable_compile_cache
 from repro.core import rber, vth_model
 
 PAPER_CYCLED = {  # part -> (AND, OR, XNOR, NOT) % at 1.5k P/E
@@ -35,4 +36,5 @@ def main(quick: bool = True) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -11,8 +11,11 @@ training-data filter (repro.data.bitmap_pipeline).
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.data import BitmapFilter
 from repro.flash import bitmap_index, speedup_table
+
+enable_compile_cache()
 
 rng = np.random.default_rng(11)
 n_users = 131072                      # one page worth of users

@@ -11,7 +11,10 @@ simulator + Pallas kernels.
 import numpy as np
 
 from repro.api import ComputeSession
+from repro.compile_cache import enable_compile_cache
 from repro.flash import image_encryption, speedup_table
+
+enable_compile_cache()
 
 rng = np.random.default_rng(7)
 sess = ComputeSession(backend="pallas", seed=7)

@@ -12,9 +12,12 @@ the traced device timeline of everything this script just executed.
 import numpy as np
 
 from repro.api import ComputeSession
+from repro.compile_cache import enable_compile_cache
 from repro.core import encoding, rber
 from repro.flash import (TimingModel, isc_time_us, mcflash_time_us,
                          osc_time_us)
+
+enable_compile_cache()
 
 sess = ComputeSession(backend="pallas", seed=0, trace=True)
 chip = sess.chip

@@ -14,8 +14,11 @@ request-lifecycle span per query (the per-request p99 input).
 import numpy as np
 
 from repro.api import ComputeSession
+from repro.compile_cache import enable_compile_cache
 from repro.flash.geometry import SSDConfig
 from repro.serve import QueryEngine, SLOConfig
+
+enable_compile_cache()
 
 rng = np.random.default_rng(7)
 sess = ComputeSession(config=SSDConfig(page_kb=1), backend="pallas",

@@ -10,6 +10,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.serve import Engine, ServeConfig
 
@@ -53,4 +54,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

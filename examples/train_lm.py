@@ -17,6 +17,7 @@ import shutil
 import numpy as np
 
 from repro.checkpoint import delta_encode, delta_sparsity  # noqa: F401
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.data import BitmapFilter
 from repro.optim import AdamWConfig
@@ -97,4 +98,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

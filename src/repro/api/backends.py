@@ -6,7 +6,8 @@ numbers.  Two implementations ship:
 - :class:`SimBackend` — the pure-jnp oracle path (``repro.kernels.ref``),
   bit-exact reference semantics, no Pallas involvement.
 - :class:`PallasBackend` — the fused ``mlc_sense``/``bitops``/``popcount``
-  TPU kernels (interpret mode off-TPU), the production path.
+  TPU kernels (compiled on a TPU, interpreted on the CPU), the production
+  path.
 
 Both consume/produce the repo-wide lane-major packed uint32 convention, so a
 session can swap backends without touching stored data, and parity tests can
@@ -90,12 +91,14 @@ class SimBackend:
 
 
 class PallasBackend:
-    """Fused Pallas kernel backend (interpret mode automatically off-TPU)."""
+    """Fused Pallas kernel backend.  ``interpret`` resolves once, at
+    construction, through :func:`repro.kernels.ops.resolve_interpret`: a
+    backend that finds a TPU runs compiled kernels."""
 
     name = "pallas"
 
     def __init__(self, interpret: bool | None = None):
-        self.interpret = interpret
+        self.interpret = kops.resolve_interpret(interpret)
 
     def sense(self, vth: jnp.ndarray, plan: ReadPlan) -> jnp.ndarray:
         return kops.sense_plan(vth, plan, interpret=self.interpret)

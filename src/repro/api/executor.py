@@ -718,7 +718,7 @@ class Executor:
         # backend name — interpret mode, the tiling width, and the device-
         # placement layout complete the key.  rids are NOT keyed: isomorphic
         # batches from different request mixes replay one executable.
-        key = (getattr(sess.backend, "interpret", None),
+        key = (bool(getattr(sess.backend, "interpret", False)),
                self.max_fused_operands, sig, popcounts, layout)
         if tracer is not None:
             hit = key in self.cache

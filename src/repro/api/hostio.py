@@ -6,7 +6,7 @@ so on multi-wave workloads the host transfer of result *k* serializes with
 the sensing of result *k+1*.  :class:`HostDrainQueue` breaks that chain:
 
 - :meth:`~HostDrainQueue.submit` starts the device->host copy *asynchronously*
-  (``jax.Array.copy_to_host_async`` when the backend provides it) and
+  (``jax.Array.copy_to_host_async``) and
   returns a :class:`DrainHandle` immediately — the caller goes on to lower
   and dispatch the next expression while the transfer streams.
 - The queue is **bounded** (``depth`` in-flight transfers, default 2 — the
@@ -48,34 +48,23 @@ class DrainHandle:
         #: owning request id (serving engine attribution), or None
         self.rid = rid
         # start the DMA now; resolution in result() then only waits, it
-        # doesn't initiate (older jax backends without the hook degrade to
-        # a synchronous copy at result() time)
-        start = getattr(array, "copy_to_host_async", None)
-        if callable(start):
-            start()
+        # doesn't initiate (a numpy payload is host memory already)
+        if not isinstance(array, np.ndarray):
+            array.copy_to_host_async()
 
     @property
     def done(self) -> bool:
         """True once the bytes are host-resident — a non-blocking probe.
 
         Resolution order: a memoized :meth:`result` is definitively done; a
-        plain ``np.ndarray`` submission is already host memory; otherwise ask
-        the backend's ``jax.Array.is_ready()`` when it exists (True only once
-        the async copy has landed).  Backends without the probe report False
-        until :meth:`result` resolves — callers must treat ``done`` as a
-        readiness *hint*, never a completion requirement.
+        plain ``np.ndarray`` submission is already host memory; otherwise
+        ``jax.Array.is_ready()`` answers (True once the array is computed).
         """
         if self._out is not None:
             return True
         if isinstance(self._array, np.ndarray):
             return True
-        probe = getattr(self._array, "is_ready", None)
-        if callable(probe):
-            try:
-                return bool(probe())
-            except Exception:
-                return False
-        return False
+        return bool(self._array.is_ready())
 
     def result(self) -> np.ndarray:
         if self._out is None:

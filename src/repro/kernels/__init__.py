@@ -1,9 +1,9 @@
 """Pallas TPU kernels for the MCFlash hot paths.
 
-- ``mlc_sense``: fused threshold sense + lane-major bit-pack (hard/MSB/SBR).
+- ``mlc_sense``: fused threshold sense + lane-major bit-pack (lsb/msb/sbr/parity).
 - ``bitops``: packed multi-operand AND/OR/XOR chains.
 - ``popcount``: per-row popcount reduce.
-- ``ops``: public jit wrappers (interpret=True off-TPU).
+- ``ops``: public jit wrappers (compiled on a TPU, interpreted on the CPU).
 - ``ref``: pure-jnp oracles + the packing convention.
 """
 from repro.kernels import ops, ref

@@ -27,12 +27,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.mlc_sense import _sense_bits, pad_refs
+from repro.kernels.mlc_sense import (LANES, ROW_TILE, TILE_COLS, WORD_BITS,
+                                    _sense_bits, pack_tile, pad_refs)
+from repro.kernels.popcount import _popcount
 
-LANES = 128
-WORD_BITS = 32
-TILE_COLS = LANES * WORD_BITS  # 4096
-ROW_TILE = 8                   # sublane-aligned row tile
 #: VMEM ceiling the automatic column-tile widening respects on compiled
 #: backends (operand tiles resident per fused pass)
 COL_TILE_VMEM_BUDGET_BYTES = 4 * 1024 * 1024
@@ -60,13 +58,6 @@ def _auto_col_tiles(n: int, c: int, interpret: bool) -> int:
     return 1
 
 
-def _sense_tile(v: jnp.ndarray, refs_ref, kind: str, invert: bool,
-                n_refs: int = 0) -> jnp.ndarray:
-    """One (ROW_TILE, TILE_COLS) Vth tile -> boolean sense result (the one
-    read-kind implementation shared with the standalone sense kernel)."""
-    return _sense_bits(refs_ref, v, kind, invert, n_refs)
-
-
 def _combine(acc: jnp.ndarray, nxt: jnp.ndarray, op: str) -> jnp.ndarray:
     if op == "and":
         return acc & nxt
@@ -77,39 +68,20 @@ def _combine(acc: jnp.ndarray, nxt: jnp.ndarray, op: str) -> jnp.ndarray:
     raise ValueError(op)
 
 
-def _pack(bits: jnp.ndarray) -> jnp.ndarray:
-    """(ROW_TILE, k*TILE_COLS) bool -> (ROW_TILE, k*LANES) lane-major uint32
-    (each TILE_COLS-wide stripe packs independently, so k > 1 blocks pack
-    exactly like k adjacent width-1 blocks)."""
-    rows, cols = bits.shape
-    k = cols // TILE_COLS
-    b = bits.astype(jnp.uint32).reshape(rows, k, WORD_BITS, LANES)
-    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)[None, None, :, None]
-    return jnp.sum(b << shifts, axis=2,
-                   dtype=jnp.uint32).reshape(rows, k * LANES)
-
-
-def _popcount(v: jnp.ndarray) -> jnp.ndarray:
-    v = v - ((v >> 1) & jnp.uint32(0x55555555))
-    v = (v & jnp.uint32(0x33333333)) + ((v >> 2) & jnp.uint32(0x33333333))
-    v = (v + (v >> 4)) & jnp.uint32(0x0F0F0F0F)
-    return ((v * jnp.uint32(0x01010101)) >> 24).astype(jnp.int32)
-
-
 def _sense_reduce_acc(refs_ref, vth_ref, *, n: int, kind: str,
                       sense_invert: bool, op: str, invert: bool,
                       n_refs: int) -> jnp.ndarray:
     """Shared body: sense all n operand tiles, fold into one bool accumulator."""
-    acc = _sense_tile(vth_ref[0], refs_ref, kind, sense_invert, n_refs)
+    acc = _sense_bits(refs_ref, vth_ref[0], kind, sense_invert, n_refs)
     for k in range(1, n):                       # static unroll over operands
-        acc = _combine(acc, _sense_tile(vth_ref[k], refs_ref, kind,
+        acc = _combine(acc, _sense_bits(refs_ref, vth_ref[k], kind,
                                         sense_invert, n_refs), op)
     return jnp.logical_not(acc) if invert else acc
 
 
 def _sense_reduce_kernel(refs_ref, vth_ref, out_ref, *, n, kind,
                          sense_invert, op, invert, n_refs):
-    out_ref[...] = _pack(_sense_reduce_acc(
+    out_ref[...] = pack_tile(_sense_reduce_acc(
         refs_ref, vth_ref, n=n, kind=kind, sense_invert=sense_invert,
         op=op, invert=invert, n_refs=n_refs))
 
@@ -117,7 +89,7 @@ def _sense_reduce_kernel(refs_ref, vth_ref, out_ref, *, n, kind,
 def _sense_reduce_popcount_kernel(refs_ref, vth_ref, mask_ref, out_ref, *, n,
                                   kind, sense_invert, op, invert, n_refs):
     j = pl.program_id(1)
-    words = _pack(_sense_reduce_acc(
+    words = pack_tile(_sense_reduce_acc(
         refs_ref, vth_ref, n=n, kind=kind, sense_invert=sense_invert,
         op=op, invert=invert, n_refs=n_refs)) & mask_ref[...]
     pcw = _popcount(words)                      # (ROW_TILE, k*LANES)

@@ -17,6 +17,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -52,14 +53,28 @@ def _sense_bits(refs_ref, v: jnp.ndarray, kind: str, invert: bool,
     return jnp.logical_not(bits) if invert else bits
 
 
+def pack_tile(bits: jnp.ndarray) -> jnp.ndarray:
+    """(rows, k*TILE_COLS) bool -> (rows, k*LANES) lane-major uint32.
+
+    Each TILE_COLS-wide stripe packs independently (so a k-stripe block
+    packs exactly like k adjacent one-stripe blocks); the reduction runs
+    over the 32 sublane groups and the lanes stay 128 wide.  Mosaic has no
+    reduction over unsigned integers, so the shifted bits sum in int32 —
+    they are disjoint, so the sum is their OR, bit 31 included — and the
+    words are bitcast to uint32.
+    """
+    rows, cols = bits.shape
+    k = cols // TILE_COLS
+    b = bits.astype(jnp.int32).reshape(rows, k, WORD_BITS, LANES)
+    shifts = jnp.arange(WORD_BITS, dtype=jnp.int32)[None, None, :, None]
+    words = jnp.sum(b << shifts, axis=2, dtype=jnp.int32)
+    return lax.bitcast_convert_type(words, jnp.uint32).reshape(rows, k * LANES)
+
+
 def _sense_kernel(refs_ref, vth_ref, out_ref, *, kind: str, invert: bool,
                   n_refs: int):
-    v = vth_ref[...]                                   # (ROW_TILE, TILE_COLS) f32
-    bits = _sense_bits(refs_ref, v, kind, invert, n_refs)
-    # Lane-major pack: reduction over the 32 sublane groups, lanes stay 128.
-    b = bits.astype(jnp.uint32).reshape(v.shape[0], WORD_BITS, LANES)
-    shifts = jnp.arange(WORD_BITS, dtype=jnp.uint32)[None, :, None]
-    out_ref[...] = jnp.sum(b << shifts, axis=1, dtype=jnp.uint32)
+    out_ref[...] = pack_tile(_sense_bits(refs_ref, vth_ref[...], kind, invert,
+                                         n_refs))
 
 
 def pad_refs(refs: jnp.ndarray) -> jnp.ndarray:

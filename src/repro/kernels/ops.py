@@ -1,7 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; TPU v5e
-is the compilation *target*) and to False on a real TPU backend.
+The kernels run compiled on a TPU and in interpret mode on the CPU backend
+(tests); :func:`resolve_interpret` refuses every other pairing, so a process
+that finds the chip never falls back to the interpreter.
 """
 from __future__ import annotations
 
@@ -22,8 +23,17 @@ MAX_REFS = kernel_ref.MAX_REFS
 pad_refs = _mlc.pad_refs
 
 
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None = None) -> bool:
+    """Interpret mode for the current JAX backend: True on the CPU, False on
+    a TPU.  ``None`` picks it; an explicit value that disagrees (or any other
+    backend) raises instead of running the kernels somewhere unintended."""
+    backend = jax.default_backend()
+    want = {"cpu": True, "tpu": False}.get(backend)
+    if want is None or interpret not in (None, want):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on a TPU and interpreted on the CPU "
+            f"only; got backend {backend!r} with interpret={interpret!r}")
+    return want
 
 
 def pad_rows(x: jnp.ndarray, multiple: int = ROW_TILE) -> tuple[jnp.ndarray, int]:
@@ -38,8 +48,7 @@ def pad_rows(x: jnp.ndarray, multiple: int = ROW_TILE) -> tuple[jnp.ndarray, int
 def mlc_sense(vth: jnp.ndarray, refs, *, kind: str, invert: bool = False,
               n_refs: int = 0, interpret: bool | None = None) -> jnp.ndarray:
     """Fused sense+pack: (R, C) Vth -> (R, C//32) packed uint32."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     padded, r = pad_rows(vth)
     out = _mlc.mlc_sense(padded, jnp.asarray(refs, jnp.float32),
                          kind=kind, invert=invert, n_refs=n_refs,
@@ -63,8 +72,7 @@ def sense_reduce_plan(vth: jnp.ndarray, plan, *, op: str, invert: bool = False,
                       interpret: bool | None = None) -> jnp.ndarray:
     """Fused megakernel: (N, R, C) same-plan Vth -> (R, C//32) packed
     op-reduction, without round-tripping per-operand partials through HBM."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     refs, kind, sense_invert, n_refs = _plan_parts(plan)
     n, r, c = vth.shape
     pad_r = (-r) % ROW_TILE
@@ -80,8 +88,7 @@ def sense_reduce_popcount_plan(vth: jnp.ndarray, plan, mask: jnp.ndarray, *,
                                op: str, invert: bool = False,
                                interpret: bool | None = None) -> jnp.ndarray:
     """Fused megakernel + masked popcount: (N, R, C) Vth -> (R,) int32."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     refs, kind, sense_invert, n_refs = _plan_parts(plan)
     n, r, c = vth.shape
     pad_r = (-r) % ROW_TILE
@@ -99,8 +106,7 @@ def sense_reduce_popcount_plan(vth: jnp.ndarray, plan, mask: jnp.ndarray, *,
 def bitwise_reduce(stack: jnp.ndarray, *, op: str, invert: bool = False,
                    interpret: bool | None = None) -> jnp.ndarray:
     """(N, R, W) packed uint32 -> (R, W) op-reduction over operands."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     n, r, w = stack.shape
     pad_r = (-r) % _bitops.ROW_TILE
     pad_w = (-w) % _bitops.COL_TILE
@@ -112,8 +118,7 @@ def bitwise_reduce(stack: jnp.ndarray, *, op: str, invert: bool = False,
 
 def popcount_rows(words: jnp.ndarray, *, interpret: bool | None = None) -> jnp.ndarray:
     """(R, W) packed uint32 -> (R,) int32 popcounts."""
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = resolve_interpret(interpret)
     padded, r = pad_rows(words)
     pad_w = (-padded.shape[1]) % _pop.COL_TILE      # zero words count nothing
     if pad_w:

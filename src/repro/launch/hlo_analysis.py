@@ -430,8 +430,6 @@ def analyze_text(hlo_text: str) -> Cost:
 
 def analyze(compiled) -> Roofline:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):       # older jaxlib: list of per-module dicts
-        ca = ca[0] if ca else {}
     cost = analyze_text(compiled.as_text())
     return Roofline(
         flops=cost.flops,

@@ -8,16 +8,10 @@ import; smoke tests and benches see the real single CPU device.
 from __future__ import annotations
 
 import jax
-
-try:                                  # jax >= 0.5
-    from jax.sharding import AxisType
-except ImportError:                   # older jax: meshes are Auto-typed already
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 

@@ -68,7 +68,7 @@ def test_randomized_cross_encoding_parity(encoding, dies):
     cfg = _config(dies)
     n = cfg.page_bits
 
-    @settings(max_examples=2)
+    @settings(max_examples=2, deadline=None)
     @given(st.integers(0, 2**31 - 1))
     def run(seed):
         rng = np.random.default_rng(seed)

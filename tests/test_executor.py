@@ -42,7 +42,7 @@ def _random_expr(rng, vecs, bits, depth=0):
     return expr, oracle
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(st.integers(0, 2**31 - 1))
 def test_randomized_dags_backend_parity(seed):
     """Random DAGs produce identical packed words on sim and pallas, both

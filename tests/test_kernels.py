@@ -5,8 +5,11 @@ import numpy as np
 import pytest
 from repro.testing.hypothesis_compat import given, settings, st
 
+from repro.api import PallasBackend
 from repro.core import mcflash, vth_model
 from repro.kernels import ops, ref
+from repro.kernels.fused import sense_reduce, sense_reduce_popcount
+from repro.kernels.mlc_sense import mlc_sense as mlc_sense_kernel
 
 
 @pytest.mark.parametrize("rows", [8, 16])
@@ -46,6 +49,52 @@ def test_mlc_sense_row_padding(rng):
     vth = jnp.asarray(rng.normal(2.0, 2.0, (5, 4096)).astype(np.float32))
     got = ops.mlc_sense(vth, [1.9, 0, 0, 0], kind="lsb")
     assert got.shape == (5, 128)
+
+
+def _top_bit_tile(rng) -> np.ndarray:
+    """(8, 4096) bits, random except that bit 31 of every packed word (cell
+    columns 31*128 .. 32*128-1) is set."""
+    bits = (rng.random((8, 4096)) < 0.5).astype(np.uint8)
+    bits[:, 31 * 128:] = 1
+    return bits
+
+
+@pytest.mark.parametrize("kernel", ["mlc_sense", "sense_reduce",
+                                    "sense_reduce_popcount"])
+def test_pack_keeps_bit_31(kernel, rng):
+    """The kernels pack in int32 and bitcast: bit 31 must survive in every
+    word, exactly as the unsigned reference packs it."""
+    bits = _top_bit_tile(rng)
+    vth = jnp.asarray(np.where(bits == 1, -1.0, 1.0).astype(np.float32))
+    refs = jnp.zeros((1,), jnp.float32)              # lsb: bit = vth < 0
+    want = np.asarray(ref.pack_bits(jnp.asarray(bits)))
+    assert (want >> 31 == 1).all()
+    if kernel == "mlc_sense":
+        got = mlc_sense_kernel(vth, refs, kind="lsb", interpret=True)
+    elif kernel == "sense_reduce":
+        got = sense_reduce(vth[None], refs, kind="lsb", sense_invert=False,
+                           op="and", interpret=True)
+    else:
+        mask = jnp.full(want.shape, 0x80000000, jnp.uint32)   # bit 31 only
+        got = sense_reduce_popcount(vth[None], refs, mask, kind="lsb",
+                                    sense_invert=False, op="and",
+                                    interpret=True)
+        np.testing.assert_array_equal(np.asarray(got), np.full(8, 128))
+        return
+    np.testing.assert_array_equal(np.asarray(got), want)
+
+
+def test_interpret_follows_the_backend():
+    """On the CPU backend the kernels interpret; asking for compiled kernels
+    there is an error, not a silent fallback."""
+    assert jax.default_backend() == "cpu"
+    assert ops.resolve_interpret(None) is True
+    assert ops.resolve_interpret(True) is True
+    with pytest.raises(RuntimeError, match="interpret=False"):
+        ops.resolve_interpret(False)
+    with pytest.raises(RuntimeError, match="interpret=False"):
+        PallasBackend(interpret=False)
+    assert PallasBackend().interpret is True
 
 
 def test_pack_unpack_roundtrip(rng):

@@ -332,7 +332,7 @@ def test_sim_pallas_bit_identical_under_faults(encoding):
     assert results["sim"][1] == results["pallas"][1]
 
 
-@settings(max_examples=2)
+@settings(max_examples=2, deadline=None)
 @given(st.integers(0, 2**31 - 1))
 def test_randomized_dags_error_free_at_10k(seed):
     """Acceptance: randomized op DAGs over native-TLC pairs at 10k P/E

@@ -264,9 +264,11 @@ def test_drain_handle_done_probes_readiness():
 
     class _Broken(_FakeDeviceArray):
         def is_ready(self):
-            raise RuntimeError("backend without a probe")
+            raise RuntimeError("device error")
 
-    assert not DrainHandle(_Broken(np.zeros(2, np.uint32)), 8).done
+    # a failing readiness probe surfaces, it is not reported as "not ready"
+    with pytest.raises(RuntimeError, match="device error"):
+        DrainHandle(_Broken(np.zeros(2, np.uint32)), 8).done
 
     # real jax arrays report done once committed
     dev = DrainHandle(jnp.arange(4, dtype=jnp.uint32), 16)

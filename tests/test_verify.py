@@ -185,7 +185,7 @@ def test_mutation_suite(encoding, dies):
     invariant, the unmutated corpus verifies clean, and every mutation
     class finds at least one applicable plan per configuration."""
 
-    @settings(max_examples=2)
+    @settings(max_examples=2, deadline=None)
     @given(st.integers(0, 2**31 - 1))
     def run(seed):
         rng = np.random.default_rng(seed)
