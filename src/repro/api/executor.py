@@ -702,46 +702,46 @@ class Executor:
         # FTL's realignment copybacks inside it also land as device spans
         with traced(tracer, "lower", "lower", roots=len(nodes)):
             plan = _Lowering(sess).lower_many(nodes, rids)
+        with traced(None, "executable"):
+            sig = plan.signature(sess.backend.name)
+            layout = self._placement_layout(plan)
         # static verification runs at lowering time, before any accounting
         # or dispatch; memoized per signature so cache-hit plans pay ~nothing
-        sig = plan.signature(sess.backend.name)
-        sess.verify_lowered_plan(plan, sig)
-        layout = self._placement_layout(plan)
-        self._account(plan, placed=layout is not None,
-                      attributed=rids is not None)
+        with traced(None, "verify"):
+            sess.verify_lowered_plan(plan, sig)
+        with traced(None, "account"):
+            self._account(plan, placed=layout is not None,
+                          attributed=rids is not None)
         ledger = sess.device.ledger
         if sess.verifier.enabled and ledger.mode != "independent":
             # the overlap-consistency invariant audits the ledger's freshly
             # booked step log: transfers may overlap only LATER waves' work
-            check_overlap_consistency(ledger, plan=plan)
+            with traced(None, "verify"):
+                check_overlap_consistency(ledger, plan=plan)
         # the cache is per-device (one chip), and signature() leads with the
         # backend name — interpret mode, the tiling width, and the device-
         # placement layout complete the key.  rids are NOT keyed: isomorphic
         # batches from different request mixes replay one executable.
         key = (bool(getattr(sess.backend, "interpret", False)),
                self.max_fused_operands, sig, popcounts, layout)
-        if tracer is not None:
-            hit = key in self.cache
-            tracer.instant("cache", "executable-hit" if hit
-                           else "executable-miss",
-                           waves=len(plan.waves), groups=len(plan.groups))
-            evictions0 = self.cache.evictions
 
-            def build():
-                with tracer.span("compile", "build-executable",
-                                 waves=len(plan.waves)):
-                    return (self._build_placed(plan, popcounts)
-                            if layout is not None
-                            else self._build(plan, popcounts))
-        else:
-            def build():
+        def build():
+            with traced(tracer, "compile", "build-executable",
+                        waves=len(plan.waves)):
                 return (self._build_placed(plan, popcounts)
                         if layout is not None
                         else self._build(plan, popcounts))
-        fn = self.cache.get(key, build)
-        if tracer is not None and self.cache.evictions > evictions0:
-            tracer.instant("cache", "executable-evicted",
-                           evicted=self.cache.evictions - evictions0)
+
+        with traced(None, "executable"):
+            if tracer is not None:
+                tracer.instant("cache", "executable-hit" if key in self.cache
+                               else "executable-miss",
+                               waves=len(plan.waves), groups=len(plan.groups))
+                evictions0 = self.cache.evictions
+            fn = self.cache.get(key, build)
+            if tracer is not None and self.cache.evictions > evictions0:
+                tracer.instant("cache", "executable-evicted",
+                               evicted=self.cache.evictions - evictions0)
         dev = sess.device
         # The arena shard-gathers run OUTSIDE the cached executable (one
         # gather per die shard touched), so executable input shapes depend
@@ -753,15 +753,18 @@ class Executor:
         place = layout is None
         with traced(tracer, "dispatch", "dispatch-waves",
                     waves=len(plan.waves)):
-            group_vth = tuple(dev.vth_stack(g.wls, place=place)
-                              for g in plan.groups)
-            fused_vth = tuple(dev.vth_stack(st.fused.wls, place=place)
-                              for st in plan.steps if st.fused is not None)
-            masks = tuple(sess.tail_mask(nb, w) for nb, w
-                          in zip(n_bits_list, plan.all_root_words))
-            if layout is not None:
-                masks = tuple(dev.arena.to_compute(m) for m in masks)
-            return fn(group_vth, fused_vth, masks)
+            with traced(None, "gather"):
+                group_vth = tuple(dev.vth_stack(g.wls, place=place)
+                                  for g in plan.groups)
+                fused_vth = tuple(dev.vth_stack(st.fused.wls, place=place)
+                                  for st in plan.steps
+                                  if st.fused is not None)
+            with traced(None, "launch"):
+                masks = tuple(sess.tail_mask(nb, w) for nb, w
+                              in zip(n_bits_list, plan.all_root_words))
+                if layout is not None:
+                    masks = tuple(dev.arena.to_compute(m) for m in masks)
+                return fn(group_vth, fused_vth, masks)
 
     def _account(self, plan: ExecPlan, placed: bool = False,
                  attributed: bool = False) -> None:
@@ -828,7 +831,6 @@ class Executor:
             if per_die:
                 dev.ledger.add_die_batch(per_die, uj, commands=cmds,
                                          label=label, wave=wi, rids=rid_tag)
-                sess.metrics.histogram("wave_dies").observe(len(per_die))
             if per_ch:
                 dev.ledger.add_channel_batch(
                     per_ch, label=f"wave {wi}: dma" if parts else None,

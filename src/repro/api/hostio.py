@@ -26,6 +26,8 @@ from typing import Callable, Deque, List, Optional
 
 import numpy as np
 
+from repro.obs.trace import traced
+
 __all__ = ["DrainHandle", "HostDrainQueue", "DEFAULT_DRAIN_DEPTH"]
 
 #: in-flight transfers the bounded queue holds — 2 == classic double buffer
@@ -68,7 +70,8 @@ class DrainHandle:
 
     def result(self) -> np.ndarray:
         if self._out is None:
-            self._out = np.asarray(self._array)
+            with traced(None, "drain.wait"):
+                self._out = np.asarray(self._array)
             self._array = None          # drop the device buffer reference
         return self._out
 
@@ -109,7 +112,8 @@ class HostDrainQueue:
             oldest = self._pending.popleft()
             if self._on_block is not None:
                 self._on_block()
-            oldest.result()
+            with traced(None, "drain.block"):
+                oldest.result()
         return handle
 
     def drain(self) -> List[DrainHandle]:

@@ -37,7 +37,7 @@ from repro.core.mcflash import ReadPlan
 from repro.core.vth_model import ChipModel
 from repro.kernels import ops as kops
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.trace import Tracer
+from repro.obs.trace import Tracer, traced
 from repro.reliability import FaultConfig, FaultModel
 from repro.verify import PlanContext, PlanVerifier
 
@@ -158,7 +158,6 @@ class ComputeSession:
             self.metrics.counter(name, desc)
         self.metrics.gauge("max_concurrent_dies",
                            "widest per-wave die concurrency seen")
-        self.metrics.histogram("wave_dies", "concurrent dies per wave")
         self.metrics.histogram("fused_operands", "operands per megakernel")
         #: bounded async controller->host drain queue backing
         #: :meth:`materialize_async` — transfers stream while the next
@@ -364,7 +363,8 @@ class ComputeSession:
         reliability layer every root materializes as words first (the fused
         on-device popcount would hide bit errors), is verified/recovered per
         root, and counts fold host-side."""
-        nodes = [simplify(e.node) for e in exprs]
+        with traced(None, "lower"):
+            nodes = [simplify(e.node) for e in exprs]
         n_bits = [e.n_bits for e in exprs]
         rid_list = list(rids) if rids is not None else None
         if self.reliability is not None:
@@ -413,8 +413,9 @@ class ComputeSession:
         assert len(popcounts) == len(exprs), (len(popcounts), len(exprs))
         outs = self._run_batch(exprs, popcounts, rids)
         rid_list = list(rids) if rids is not None else [None] * len(exprs)
-        return [self.host_queue.submit(out, rid=rid)
-                for out, rid in zip(outs, rid_list)]
+        with traced(None, "drain.submit"):
+            return [self.host_queue.submit(out, rid=rid)
+                    for out, rid in zip(outs, rid_list)]
 
     def tail_mask(self, n_bits: int, total_words: int) -> jnp.ndarray:
         """Packed (total_words,) mask zeroing page-padding bits past

@@ -19,6 +19,14 @@ context manager, plus instant events (cache hits/misses/evictions).  Both
 clocks export into one Chrome trace-event JSON (``chrome://tracing`` /
 Perfetto loadable) as separate processes, and into the human-readable text
 report in :mod:`repro.obs.report`.
+
+Every host span also reaches the JAX profiler: :func:`traced` (and so
+:meth:`Tracer.span`) opens a ``jax.profiler.TraceAnnotation`` named
+``repro.<category>``, with or without a tracer.  Under
+``jax.profiler.start_trace`` or the profiler server these land on the clock
+of the device's ``XLA Ops``, so an idle device gap can be put down to the
+host phase that covers it; with no profiler session collecting, one costs
+about a microsecond.
 """
 from __future__ import annotations
 
@@ -137,10 +145,12 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, category: str, name: str, **args):
         """Wall-clock span around a host-side phase (lowering, compile,
-        dispatch, FTL realignment)."""
+        dispatch, FTL realignment), also the profiler span
+        ``repro.<category>``."""
         t0 = self._now_us()
         try:
-            yield
+            with _profiler_span(category):
+                yield
         finally:
             self._push(self.wall_spans,
                        Span(name, category, "wall", t0,
@@ -248,9 +258,26 @@ class Tracer:
         self._die_steps = self._channel_steps = 0
 
 
-def traced(tracer: Optional[Tracer], category: str, name: str, **args):
-    """``tracer.span(...)`` that degrades to a no-op when tracing is off —
-    instrumentation points stay one-liners."""
-    if tracer is None:
-        return contextlib.nullcontext()
+_TraceAnnotation = None
+
+
+def _profiler_span(category: str):
+    """``jax.profiler.TraceAnnotation("repro.<category>")``, keyword-free so
+    that it stays cheap on the per-batch path.  JAX is imported on first use,
+    so ``repro.obs.metrics`` and ``report`` import without it."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation("repro." + category)
+
+
+def traced(tracer: Optional[Tracer], category: str,
+           name: Optional[str] = None, **args):
+    """One instrumentation point, two sinks: the profiler span
+    ``repro.<category>`` always, and with a ``tracer`` and a ``name`` the
+    tracer's wall span ``name`` with ``args`` as well — instrumentation
+    points stay one-liners."""
+    if tracer is None or name is None:
+        return _profiler_span(category)
     return tracer.span(category, name, **args)

@@ -29,14 +29,21 @@ batches preempts the score order entirely (it MUST ship in the next batch).
 oldest request past the bound — and admission past ``max_queue_depth``
 auto-dispatches to bound queue memory.
 
-**Observability.**  Every die/channel span the tracer emits for a serve
-batch carries the owning request ids (``args["rids"]``), each completed
-request stamps a wall-clock ``serve``-category span (admit -> result
-resolved, tagged ``rid``), and the engine's typed metrics registry exposes
+**Observability.**  Under ``jax.profiler.start_trace`` or the profiler
+server, each :meth:`~QueryEngine.step` is a ``repro.serve.step`` span on the
+clock of the device's ``XLA Ops``, split into ``repro.lower``,
+``repro.verify``, ``repro.account``, ``repro.executable``, ``repro.gather``,
+``repro.launch`` and the drain's ``repro.drain.submit`` /
+``repro.drain.block`` / ``repro.drain.wait`` (:func:`repro.obs.traced`); no
+flag turns them on.  The engine's typed metrics registry exposes
 ``requests_admitted`` / ``requests_completed`` / ``batches_dispatched`` /
-``queue_depth`` alongside the session's ``coalesced_sense_groups`` /
-``waves_shared`` counters — per-request p99 falls directly out of the
-exported Chrome trace.
+``queue_depth`` and the admission-to-dispatch wait ``queue_wait_us``
+alongside the session's ``coalesced_sense_groups`` / ``waves_shared``
+counters.  With ``ComputeSession(trace=True)`` the modelled NAND timeline
+is kept too: every die/channel span of a serve batch carries the owning
+request ids (``args["rids"]``), and each completed request stamps a
+wall-clock ``serve``-category span (admit -> result resolved, tagged
+``rid``) in the exported Chrome trace.
 """
 from __future__ import annotations
 
@@ -47,6 +54,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 from repro.obs.metrics import MetricsRegistry
+from repro.obs.trace import traced
 
 __all__ = ["QueryEngine", "QueryTicket", "SLOConfig"]
 
@@ -148,8 +156,8 @@ class QueryEngine:
                              "partial batches forced by max_delay_us")
         self.metrics.gauge("queue_depth", "pending admission-queue requests")
         self.metrics.histogram("batch_requests", "requests per batch")
-        self.metrics.histogram("request_latency_us",
-                               "admit -> result wall latency")
+        self.metrics.histogram("queue_wait_us",
+                               "admit -> dispatch wall wait per request")
         tracer = session.trace
         if tracer is not None:
             # flags the exported trace as a serving run: check_trace then
@@ -211,25 +219,30 @@ class QueryEngine:
         shared lowering + one shared wave schedule on the session."""
         if not self._queue:
             return 0
-        batch = self._form_batch()
-        queued = {t.rid for t in batch}
-        self._queue = [t for t in self._queue if t.rid not in queued]
-        for t in self._queue:
-            t.waited_batches += 1
-        bi = self._batches
-        self._batches += 1
-        handles = self.session.materialize_batch_async(
-            [t._expr for t in batch],
-            popcount=[t.popcount for t in batch],
-            rids=[t.rid for t in batch])
-        for t, h in zip(batch, handles):
-            t._handle = h
-            t.batch = bi
-            t._expr = None                     # the DAG is lowered; drop it
-        self.metrics.counter("batches_dispatched").add(1)
-        self.metrics.histogram("batch_requests").observe(len(batch))
-        self.metrics.gauge("queue_depth").set(len(self._queue))
-        return len(batch)
+        with traced(None, "serve.step"):
+            batch = self._form_batch()
+            queued = {t.rid for t in batch}
+            self._queue = [t for t in self._queue if t.rid not in queued]
+            for t in self._queue:
+                t.waited_batches += 1
+            now = self._now_us()
+            waits = self.metrics.histogram("queue_wait_us")
+            for t in batch:
+                waits.observe(now - t.submitted_us)
+            bi = self._batches
+            self._batches += 1
+            handles = self.session.materialize_batch_async(
+                [t._expr for t in batch],
+                popcount=[t.popcount for t in batch],
+                rids=[t.rid for t in batch])
+            for t, h in zip(batch, handles):
+                t._handle = h
+                t.batch = bi
+                t._expr = None                 # the DAG is lowered; drop it
+            self.metrics.counter("batches_dispatched").add(1)
+            self.metrics.histogram("batch_requests").observe(len(batch))
+            self.metrics.gauge("queue_depth").set(len(self._queue))
+            return len(batch)
 
     def poll(self) -> int:
         """Dispatch a (possibly partial) batch only when the SLO demands
@@ -248,13 +261,12 @@ class QueryEngine:
 
     # -- completion ----------------------------------------------------------
     def _completed(self, ticket: QueryTicket) -> None:
-        latency = self._now_us() - ticket.submitted_us
         self.metrics.counter("requests_completed").add(1)
-        self.metrics.histogram("request_latency_us").observe(latency)
         tracer = self.session.trace
         if tracer is not None:
             # request-lifecycle span (admit -> result resolved): the
             # per-request latency attribution the p99 breakdown reads
+            latency = self._now_us() - ticket.submitted_us
             tracer.mark_span("serve", f"request {ticket.rid}",
                              ticket.submitted_us, latency, rid=ticket.rid,
                              batch=ticket.batch, popcount=ticket.popcount,
@@ -282,6 +294,8 @@ class QueryEngine:
             "delay_bound_dispatches": int(
                 self.metrics["delay_bound_dispatches"].value),
             "queue_depth": int(self.metrics["queue_depth"].value),
+            "queue_wait_us_sum": self.metrics["queue_wait_us"].total,
+            "queue_waits": self.metrics["queue_wait_us"].count,
             "coalesced_sense_groups": sess.coalesced_sense_groups,
             "waves_shared": sess.waves_shared,
             "sense_waves": sess.sense_waves,

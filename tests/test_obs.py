@@ -113,12 +113,43 @@ def test_tracer_max_spans_drops_not_grows():
 
 def test_traced_nullcontext_when_off():
     with traced(None, "lower", "lower"):
-        pass                               # no tracer -> plain nullcontext
+        pass                               # no tracer -> profiler span only
     tr = Tracer()
     with traced(tr, "lower", "lower", waves=2):
         pass
     assert [s.name for s in tr.wall_spans] == ["lower"]
     assert tr.wall_spans[0].args == {"waves": 2}
+
+
+def test_attached_tracer_records_host_spans_as_before():
+    """The profiler sink leaves the tracer's wall spans as they were: the
+    executor's lowering, executable build on a miss and wave dispatch keep
+    their names, categories and arguments."""
+    sess = _traced_session()
+    _run_some_ops(sess)
+    spans = {s.name: s for s in sess.trace.wall_spans}
+    assert {"lower", "build-executable", "dispatch-waves"} <= set(spans)
+    assert spans["lower"].category == "lower"
+    assert spans["lower"].args == {"roots": 1}
+    assert spans["build-executable"].category == "compile"
+    assert spans["dispatch-waves"].category == "dispatch"
+    assert spans["build-executable"].args == spans["dispatch-waves"].args
+    assert spans["dispatch-waves"].args["waves"] >= 1
+
+
+def test_traced_feeds_the_profiler_with_or_without_a_tracer(profiled):
+    tr = Tracer()
+
+    def spans():
+        with traced(None, "verify"):
+            pass
+        with traced(tr, "lower", "lower", roots=2):
+            pass
+
+    got = profiled(spans)
+    assert len(got["repro.verify"]) == len(got["repro.lower"]) == 1
+    assert [(s.name, s.args) for s in tr.wall_spans] == [("lower",
+                                                          {"roots": 2})]
 
 
 # -- stats() back-compat over the registry ------------------------------------

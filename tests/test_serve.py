@@ -309,3 +309,52 @@ def test_lm_engine_decode_call_count():
     assert eng.decode_calls == 4               # not 5: no dead final step
     eng.generate(prompts, max_new_tokens=1)    # degenerate: no decode at all
     assert eng.decode_calls == 4
+
+
+#: the profiler spans of one ``QueryEngine.step`` (``repro.drain.wait``
+#: nests inside ``repro.drain.block`` there)
+STEP_SPANS = ("repro.lower", "repro.verify", "repro.account",
+              "repro.executable", "repro.gather", "repro.launch",
+              "repro.drain.submit", "repro.drain.block", "repro.drain.wait")
+
+
+def test_step_spans_on_the_profiler_clock(profiled):
+    """One batch wider than the drain depth, traced by ``jax.profiler``:
+    every span of the step appears, inside the step's own span, and the
+    drain's backpressure shows as ``repro.drain.block``."""
+    sess = _session("sim")
+    exprs, pcs, oracles = _workload(sess, np.random.default_rng(5),
+                                    n_requests=4)
+    assert len(exprs) > sess.host_queue.depth
+    eng = QueryEngine(sess, SLOConfig(max_batch_requests=4))
+    tickets = [eng.submit(e, popcount=pc) for e, pc in zip(exprs, pcs)]
+    spans = profiled(eng.step)
+    (step,) = spans["repro.serve.step"]
+    for name in STEP_SPANS:
+        assert name in spans, (name, sorted(spans))
+        assert all(step[0] <= a <= b <= step[1] for a, b in spans[name]), \
+            name
+    # two transfers in flight, so the third and fourth result block
+    assert len(spans["repro.drain.block"]) == 2
+    for t, oracle in zip(tickets, oracles):
+        _resolve(t, oracle)
+
+
+def test_queue_wait_counts_each_dispatched_request():
+    """``queue_wait_us`` observes admission to dispatch once per request."""
+    sess = _session("sim")
+    exprs, pcs, oracles = _workload(sess, np.random.default_rng(6))
+    eng = QueryEngine(sess, SLOConfig(max_batch_requests=3))
+    tickets = [eng.submit(e, popcount=pc) for e, pc in zip(exprs, pcs)]
+    assert eng.stats()["queue_waits"] == 0
+    eng.step()
+    st = eng.stats()
+    assert st["queue_waits"] == 3 and st["queue_wait_us_sum"] > 0
+    eng.drain()
+    st = eng.stats()
+    assert st["queue_waits"] == len(exprs) == st["requests_admitted"]
+    # later batches waited through the earlier ones
+    assert st["queue_wait_us_sum"] / st["queue_waits"] > \
+        eng.metrics["queue_wait_us"].min
+    for t, oracle in zip(tickets, oracles):
+        _resolve(t, oracle)
