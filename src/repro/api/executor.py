@@ -743,22 +743,25 @@ class Executor:
                 tracer.instant("cache", "executable-evicted",
                                evicted=self.cache.evictions - evictions0)
         dev = sess.device
-        # The arena shard-gathers run OUTSIDE the cached executable (one
-        # gather per die shard touched), so executable input shapes depend
-        # only on the plan signature — shard growth must not retrace cached
-        # executables.  With mapped shards (placed dispatch) the single-die
-        # gathers stay on their OWN shard's device instead of funneling
-        # through the primary — each wave unit's kernel then dispatches on
-        # the device its inputs committed to.
-        place = layout is None
+        # The arena gathers run OUTSIDE the cached executable, so executable
+        # input shapes depend only on the plan signature — shard growth must
+        # not retrace cached executables.  Unplaced, every stack of the
+        # batch comes out of ONE gather program; with mapped shards (placed
+        # dispatch) each unit gathers on its OWN shard's device instead of
+        # funneling through the primary — each wave unit's kernel then
+        # dispatches on the device its inputs committed to.
         with traced(tracer, "dispatch", "dispatch-waves",
                     waves=len(plan.waves)):
             with traced(None, "gather"):
-                group_vth = tuple(dev.vth_stack(g.wls, place=place)
-                                  for g in plan.groups)
-                fused_vth = tuple(dev.vth_stack(st.fused.wls, place=place)
-                                  for st in plan.steps
-                                  if st.fused is not None)
+                stacks = [g.wls for g in plan.groups] + [
+                    st.fused.wls for st in plan.steps if st.fused is not None]
+                if layout is None:
+                    vth = dev.vth_stack_many(stacks)
+                else:
+                    vth = tuple(dev.vth_stack(wls, place=False)
+                                for wls in stacks)
+                ng = len(plan.groups)
+                group_vth, fused_vth = vth[:ng], vth[ng:]
             with traced(None, "launch"):
                 masks = tuple(sess.tail_mask(nb, w) for nb, w
                               in zip(n_bits_list, plan.all_root_words))
@@ -841,6 +844,9 @@ class Executor:
             m.counter("waves_shared").add(n_shared_waves)
         if placed:
             m.counter("placed_unit_dispatches").add(len(plan.groups) + n_fused)
+        else:
+            m.counter("arena_gather_programs").add(1)
+            m.counter("arena_gathered_stacks").add(len(plan.groups) + n_fused)
         m.counter("in_flash_senses").add(plan.senses)
         m.counter("sense_items").add(plan.items)
         m.counter("sense_batches").add(len(plan.groups) + n_fused)

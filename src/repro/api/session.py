@@ -54,6 +54,8 @@ _SESSION_COUNTERS = (
     ("megakernel_calls", "fused sense->reduce(->popcount) passes"),
     ("tiled_megakernel_splits", "fused chains split for VMEM budget"),
     ("placed_unit_dispatches", "wave units dispatched on pinned shard devices"),
+    ("arena_gather_programs", "unplaced batch arena-gather programs dispatched"),
+    ("arena_gathered_stacks", "operand stacks gathered by those programs"),
     ("host_drain_submits", "async controller->host transfers enqueued"),
     ("host_drain_blocks", "drain-queue backpressure stalls (queue full)"),
     ("coalesced_sense_groups", "batch sense groups shared by >1 request"),
@@ -476,6 +478,8 @@ class ComputeSession:
             "megakernel_calls": self.megakernel_calls,
             "tiled_megakernel_splits": self.tiled_megakernel_splits,
             "placed_unit_dispatches": self.placed_unit_dispatches,
+            "arena_gather_programs": self.arena_gather_programs,
+            "arena_gathered_stacks": self.arena_gathered_stacks,
             "host_drain": {"submits": self.host_drain_submits,
                            "blocks": self.host_drain_blocks,
                            "pending": len(self.host_queue),

@@ -4,25 +4,28 @@ The functional device used to hold per-wordline Vth tensors in a Python
 dict, so every batched sense paid a host-side ``jnp.stack`` over N separate
 device arrays.  :class:`VthArena` replaced that with a single device-resident
 2-D buffer plus a free-slot allocator: programming a wordline scatters one
-row, and a batched sense is a single ``jnp.take`` of row indices.
+row, and reading a batch of wordlines gathers their row indices.
 
 :class:`ShardedVthArena` shards that storage per die — one lazily-created
 :class:`VthArena` per die that holds data, addressed by ``(die, slot)``
-refs — so the compiled executor's per-die sense groups each gather from
-their *own* shard (one gather per shard instead of one global gather), and
-shards can optionally be pinned to distinct JAX devices (``devices=`` /
-``devices="auto"``) so multi-die dispatch maps onto real accelerator
-parallelism.
+refs — and shards can optionally be pinned to distinct JAX devices
+(``devices=`` / ``devices="auto"``) so multi-die dispatch maps onto real
+accelerator parallelism.  With unmapped shards, every operand stack a
+compiled batch needs (its per-die sense groups and cross-die fused steps)
+comes out of ONE jitted gather program (:meth:`ShardedVthArena.gather_many`)
+fed by one index upload; mapped shards gather on each shard's own device.
 
 Each shard grows geometrically (rows double, never shrink) so steady-state
 programs/reads never reallocate; freed slots are recycled LIFO per shard.
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.tlc import ENCODINGS
 
@@ -31,22 +34,45 @@ __all__ = ["VthArena", "ShardedVthArena", "SlotRef"]
 #: address of one arena row: (die, slot-within-die-shard)
 SlotRef = Tuple[int, int]
 
+#: static structure of one stack in :func:`_gather_parts`: rows taken from
+#: each of its parts (one part per die shard it touches) and whether the
+#: concatenated parts are then reordered into request order
+StackLayout = Tuple[Tuple[int, ...], bool]
+
 
 @jax.jit
 def _scatter_rows(buf: jnp.ndarray, idx: jnp.ndarray, rows: jnp.ndarray) -> jnp.ndarray:
     return buf.at[idx].set(rows)
 
 
-def _gather_parts(bufs: List[jnp.ndarray], idxs: List[jnp.ndarray]) -> jnp.ndarray:
-    """Gather rows from several shard buffers and concatenate them."""
-    return jnp.concatenate(
-        [jnp.take(b, i, axis=0) for b, i in zip(bufs, idxs)], axis=0)
+@functools.partial(jax.jit, static_argnames=("layout",))
+def _gather_parts(bufs: Tuple[jnp.ndarray, ...], idx: jnp.ndarray,
+                  layout: Tuple[StackLayout, ...]) -> Tuple[jnp.ndarray, ...]:
+    """One ``(n, page_bits)`` stack per ``layout`` entry, in ONE program.
 
-
-#: jitted cross-shard gather — ONE XLA dispatch instead of one per shard
-#: (retraces only when the (shard count, buffer/index shapes) combination
-#: changes, i.e. on shard growth); requires all shards on one device.
-_multi_gather = jax.jit(_gather_parts)
+    Stack parts consume ``bufs`` one buffer each, in order, and the next
+    ``rows`` entries of ``idx``; a permuted stack then takes its
+    concatenated parts in the order of its next ``n`` entries.  The row
+    indices come from the arena's own allocator, so they are in bounds and
+    the takes clip (a plain gather) instead of masking a NaN fill over the
+    whole output.  The compile key is the buffer shapes and ``layout``
+    alone: the same plan on other dies of equal capacity reuses it.
+    """
+    stacks, b, off = [], 0, 0
+    for part_rows, permuted in layout:
+        parts = []
+        for n in part_rows:
+            parts.append(jnp.take(bufs[b], idx[off:off + n], axis=0,
+                                  mode="clip"))
+            b += 1
+            off += n
+        stack = parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=0)
+        if permuted:
+            n = stack.shape[0]
+            stack = jnp.take(stack, idx[off:off + n], axis=0, mode="clip")
+            off += n
+        stacks.append(stack)
+    return tuple(stacks)
 
 
 class VthArena:
@@ -139,7 +165,9 @@ class VthArena:
         return jnp.asarray(list(slots), jnp.int32)
 
     def gather(self, slots: Sequence[int]) -> jnp.ndarray:
-        """(len(slots), page_bits) view of the requested rows — one take."""
+        """(len(slots), page_bits) copy of the requested rows — one eager
+        take on this shard's device (the mapped-shard path; unmapped shards
+        gather through :meth:`ShardedVthArena.gather_many`)."""
         return jnp.take(self._buf, self.rows(slots), axis=0)
 
 
@@ -285,11 +313,13 @@ class ShardedVthArena:
 
     def gather(self, refs: Sequence[SlotRef], *,
                place: bool = True) -> jnp.ndarray:
-        """(len(refs), page_bits) rows — ONE gather per touched shard.
+        """(len(refs), page_bits) rows in request order.
 
-        Die-local requests (the per-die sense groups) hit the single-shard
-        fast path; cross-die requests (a fused megakernel spanning dies)
-        concatenate the per-shard gathers and restore request order.
+        Unmapped shards gather in one program (:meth:`gather_many`).
+        Mapped shards take ONE gather per touched shard: die-local requests
+        (the per-die sense groups) hit the single-shard fast path; cross-die
+        requests (a fused megakernel spanning dies) concatenate the
+        per-shard gathers and restore request order.
 
         ``place`` controls the single-device funnel for mapped shards:
         ``True`` (default) lands the result on the primary compute device —
@@ -300,40 +330,74 @@ class ShardedVthArena:
         single kernel call cannot span devices).
         """
         refs = list(refs)
-        dies = {int(d) for d, _ in refs}
+        if not self.devices:
+            return self.gather_many([refs])[0]
+        dies, slots, perm = self._parts(refs)
         if len(dies) == 1:
-            local = self.shard(dies.pop()).gather([s for _, s in refs])
+            local = self.shard(dies[0]).gather(slots[0])
             return self._to_compute(local) if place else local
-        by_die: Dict[int, List[int]] = {}
-        pos: List[Tuple[int, int]] = []       # (die, index within die gather)
-        for die, slot in refs:
-            lst = by_die.setdefault(int(die), [])
-            pos.append((int(die), len(lst)))
-            lst.append(int(slot))
-        bufs, idxs, offs, off = [], [], {}, 0
-        for die in sorted(by_die):
-            offs[die] = off
-            shard = self.shard(die)
-            bufs.append(shard.buf)
-            idxs.append(shard.rows(by_die[die]))
-            off += len(by_die[die])
-        if self.devices is None:
-            stacked = _multi_gather(bufs, idxs)       # one fused dispatch
-        else:        # shards pinned to distinct devices: gather on each
-            # shard's device, collect the rows onto the compute device
-            stacked = jnp.concatenate(
-                [self._to_compute(jnp.take(b, i, axis=0))
-                 for b, i in zip(bufs, idxs)], axis=0)
-        perm = [offs[d] + i for d, i in pos]
-        if perm == list(range(len(perm))):
-            return stacked                    # die-sorted request (e.g. the
-            # operand-major fused batches round-robined across dies): the
-            # concat already restores request order — skip the take
+        # shards pinned to distinct devices: gather on each shard's device,
+        # collect the rows onto the compute device
+        stacked = jnp.concatenate(
+            [self._to_compute(self.shard(d).gather(s))
+             for d, s in zip(dies, slots)], axis=0)
+        if perm is None:
+            return stacked
         return jnp.take(stacked, jnp.asarray(perm, jnp.int32), axis=0)
 
-    def gather_die(self, die: int, slots: Sequence[int]) -> jnp.ndarray:
-        """Shard-local gather by raw slot ids (per-die sense group path)."""
-        return self.shard(die).gather(slots)
+    def gather_many(self, ref_lists: Sequence[Sequence[SlotRef]]
+                    ) -> Tuple[jnp.ndarray, ...]:
+        """One ``(len(refs), page_bits)`` stack per ref list, in order.
+
+        With unmapped shards this is ONE dispatch of :func:`_gather_parts`
+        and one index upload for all the stacks, whatever dies they touch —
+        what the executor's unplaced path feeds its cached executable.
+        Mapped shards live on different devices, which one program cannot
+        read, so there each stack is a :meth:`gather` of its own.
+        """
+        if self.devices:
+            return tuple(self.gather(refs) for refs in ref_lists)
+        bufs: List[jnp.ndarray] = []
+        idx: List[int] = []
+        layout: List[StackLayout] = []
+        for refs in ref_lists:
+            dies = {d for d, _ in refs}
+            if len(dies) == 1:    # the sense groups: skip the split's passes
+                bufs.append(self.shard(int(dies.pop())).buf)
+                idx += [s for _, s in refs]
+                layout.append(((len(refs),), False))
+                continue
+            dies, slots, perm = self._parts(refs)
+            bufs += [self.shard(d).buf for d in dies]
+            for part in slots:
+                idx += part
+            if perm is not None:
+                idx += perm
+            layout.append((tuple(map(len, slots)), perm is not None))
+        return _gather_parts(tuple(bufs), np.asarray(idx, np.int32),
+                             layout=tuple(layout))
+
+    @classmethod
+    def _parts(cls, refs: Sequence[SlotRef]
+               ) -> Tuple[List[int], List[List[int]], Optional[List[int]]]:
+        """Split a ref list by die shard: the dies (ascending), each die's
+        slots in request order, and the permutation that puts the
+        concatenated parts back in request order — ``None`` where they
+        already are (a die-sorted request, such as the operand-major fused
+        batches round-robined across dies)."""
+        by_die = cls._by_die(refs)
+        dies = sorted(by_die)
+        offs, off = {}, 0
+        for die in dies:
+            offs[die] = off
+            off += len(by_die[die])
+        perm = []
+        for die, _ in refs:
+            perm.append(offs[int(die)])
+            offs[int(die)] += 1
+        if perm == list(range(len(perm))):
+            perm = None
+        return dies, [by_die[d] for d in dies], perm
 
     def die_of(self, ref: SlotRef) -> int:
         return int(ref[0])

@@ -3,9 +3,9 @@
 Per-wordline Vth lives in a die-sharded device-resident
 :class:`~repro.flash.arena.ShardedVthArena` — one lazily-created
 ``(slots, page_bits)`` shard per die, addressed by ``(die, slot)`` refs —
-so a batched sense is one row-gather *per touched shard* instead of a
-host-side ``jnp.stack`` over a dict of arrays, and per-die sense groups
-from the compiled executor gather only their own die's storage.  Read plans
+so a batched sense is a row gather from device storage instead of a
+host-side ``jnp.stack`` over a dict of arrays, and the compiled executor
+gathers every operand stack of a batch in one program.  Read plans
 execute through a pluggable backend (Pallas sense kernels by default), P/E
 cycles are tracked per block, and the unified :class:`repro.api.Ledger`
 (time + energy) is threaded through every command so that application
@@ -116,13 +116,22 @@ class FlashDevice:
     # -- arena access (the compiled executor's input surface) ----------------
     def vth_stack(self, wls: List[WordlineKey], *,
                   place: bool = True) -> jnp.ndarray:
-        """(N, page_bits) Vth of a wordline batch — one gather per touched
-        die shard (die-local batches, the per-die sense groups, hit the
-        single-shard fast path).  ``place=False`` leaves a die-local gather
-        on its shard's pinned device (device-placed wave dispatch); the
-        default funnels onto the primary compute device."""
+        """(N, page_bits) Vth of a wordline batch (see
+        :meth:`ShardedVthArena.gather`).  ``place=False`` leaves a die-local
+        gather on its shard's pinned device (device-placed wave dispatch);
+        the default funnels onto the primary compute device."""
         return self.arena.gather([self._slot_of[wl] for wl in wls],
                                  place=place)
+
+    def vth_stack_many(self, wls_lists: List[List[WordlineKey]]
+                       ) -> Tuple[jnp.ndarray, ...]:
+        """One (N_i, page_bits) Vth stack per wordline batch, in order — ONE
+        gather program for them all on unmapped shards (the unplaced
+        executor's whole batch); mapped shards funnel each stack onto the
+        primary compute device as :meth:`vth_stack` does."""
+        slot_of = self._slot_of
+        return self.arena.gather_many(
+            [[slot_of[wl] for wl in wls] for wls in wls_lists])
 
     # -- commands -----------------------------------------------------------
     def program_shared_batch(self, wls: List[WordlineKey],

@@ -499,3 +499,128 @@ def test_sim_executor_never_enters_pallas(rng, monkeypatch):
     np.testing.assert_array_equal(got, np.bitwise_and.reduce(bits))
     assert sess.megakernel_calls == 1
     assert sess.popcount(expr) == int(np.sum(np.bitwise_and.reduce(bits)))
+
+
+# ---------------------- one arena gather program per batch ------------------
+
+def _per_row_stacks(dev, wls_lists):
+    """Reference gather, independent of the batch program: one shard take
+    per row, stacked in request order."""
+    return [np.stack([np.asarray(dev.arena.shard(d).gather([s]))[0]
+                      for d, s in (dev._slot_of[wl] for wl in wls)])
+            for wls in wls_lists]
+
+
+def _spy_gather(monkeypatch, dev):
+    """Spy on the batch gather: the layout of every gather program, and per
+    ``vth_stack_many`` call (the executor's ``repro.gather`` phase) the
+    wordline lists, the stacks returned, and the programs dispatched."""
+    from repro.flash import arena
+    layouts, calls = [], []
+    program, stack_many = arena._gather_parts, dev.vth_stack_many
+
+    def counted(*args, **kw):
+        layouts.append(kw["layout"])
+        return program(*args, **kw)
+
+    def spied(wls_lists):
+        n0 = len(layouts)
+        out = stack_many(wls_lists)
+        calls.append((wls_lists, out, len(layouts) - n0))
+        return out
+
+    monkeypatch.setattr(arena, "_gather_parts", counted)
+    monkeypatch.setattr(dev, "vth_stack_many", spied)
+    return layouts, calls
+
+
+def _batch_case(case, sess, bits):
+    """Vectors on pinned dies and the batch of one case, with its oracles."""
+    vecs = []
+    for i, die in zip(range(0, 6, 2), (3, 1, 2)):  # fused operands out of
+        vecs += sess.write_pair(f"v{i}", bits[i], f"v{i+1}",  # die order
+                                bits[i + 1], die=die)
+    if case == "one_group":
+        return [vecs[0] & vecs[1]], [bits[0] & bits[1]]
+    if case == "fused_cross_die":
+        return ([vecs[0] & vecs[1] & vecs[2] & vecs[3] & vecs[4] & vecs[5],
+                 vecs[2] ^ vecs[3]],
+                [np.bitwise_and.reduce(bits), bits[2] ^ bits[3]])
+    rng = np.random.default_rng(int(case.split("_")[-1]))
+    exprs, oracles = [], []
+    for _ in range(3):
+        e, o = _random_expr(rng, vecs, bits)
+        exprs.append(e)
+        oracles.append(o)
+    return exprs, oracles
+
+
+@pytest.mark.parametrize("case", ["multi_group_5", "multi_group_17",
+                                  "one_group", "fused_cross_die"])
+def test_batch_gathers_every_stack_in_one_program(case, monkeypatch):
+    """An unplaced batch gathers all its operand stacks with ONE program:
+    the stacks equal a per-row reference gather value for value, and the
+    answers equal the oracle and the sim backend's bit for bit."""
+    rng = np.random.default_rng(1)
+    bits = [(rng.random(SMALL.page_bits * 2) < 0.5).astype(np.uint8)
+            for _ in range(6)]
+    answers = {}
+    for backend in ("sim", "pallas"):
+        sess = _session(backend, seed=4)
+        dev = sess.device
+        exprs, oracles = _batch_case(case, sess, bits)
+        layouts, calls = _spy_gather(monkeypatch, dev)
+        outs = sess.materialize_batch(exprs)
+        ((stacks, got, programs),) = calls
+        assert programs == 1
+        assert sess.arena_gather_programs == 1
+        assert sess.arena_gathered_stacks == len(stacks)
+        assert sess.stats()["arena_gather_programs"] == 1
+        if case == "one_group":
+            assert len(stacks) == 1
+        elif case == "fused_cross_die":     # the program restores the
+            assert any(permuted for _, permuted in layouts[-1])  # order
+        else:
+            assert len(stacks) > 1
+        for g, want in zip(got, _per_row_stacks(dev, stacks), strict=True):
+            np.testing.assert_array_equal(np.asarray(g), want)
+        words = []
+        for out, oracle in zip(outs, oracles, strict=True):
+            got_bits = np.asarray(kops.unpack_bits(
+                jnp.asarray(out).reshape(1, -1))[0][:oracle.size])
+            np.testing.assert_array_equal(got_bits, oracle)
+            words.append(np.asarray(out))
+        answers[backend] = words
+    for s, p in zip(answers["sim"], answers["pallas"], strict=True):
+        np.testing.assert_array_equal(s, p)
+
+
+def test_batch_gather_compiles_once_across_dies_and_survives_growth(rng):
+    """One plan signature on other dies of equal shard capacity reuses the
+    compiled gather; growing the shard between two dispatches of one plan
+    recompiles only the gather (never the executable) and stays exact."""
+    from repro.flash import arena
+    sess = _session("pallas")
+    n = SMALL.page_bits
+    bits = [(rng.random(n) < 0.5).astype(np.uint8) for _ in range(6)]
+    a, b = sess.write_pair("a", bits[0], "b", bits[1], die=0)
+    c, d = sess.write_pair("c", bits[2], "d", bits[3], die=1)
+    assert sess.lower(a & b).signature("x") == sess.lower(c & d).signature("x")
+    got = sess.materialize_batch([a & b])[0]
+    size, traces = arena._gather_parts._cache_size(), sess.executor.traces
+    got2 = sess.materialize_batch([c & d])[0]
+    assert arena._gather_parts._cache_size() == size
+    assert sess.executor.traces == traces
+    for out, want in ((got, bits[0] & bits[1]), (got2, bits[2] & bits[3])):
+        np.testing.assert_array_equal(
+            np.asarray(kops.unpack_bits(out.reshape(1, -1))[0][:n]), want)
+    shard = sess.device.arena.shard(0)
+    cap = shard.capacity
+    for i in range(cap):                # fill die 0's shard past capacity
+        sess.write_pair(f"g{i}a", bits[4], f"g{i}b", bits[5], die=0)
+    assert shard.capacity > cap
+    again = sess.materialize_batch([a & b])[0]
+    np.testing.assert_array_equal(
+        np.asarray(kops.unpack_bits(again.reshape(1, -1))[0][:n]),
+        bits[0] & bits[1])
+    assert sess.executor.traces == traces

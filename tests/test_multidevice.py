@@ -285,3 +285,40 @@ def test_overlap_makespan_beats_sync_on_multiwave_dag():
     assert ov.makespan_us() <= sy.makespan_us()
     assert ov.makespan_us() < sy.makespan_us()      # strict on >=3 waves
     assert ov.overlapped_channel_us > 0
+
+
+@pytest.mark.parametrize("backend", ["pallas", "sim"])
+def test_placed_units_gather_on_their_shards_device(backend, monkeypatch):
+    """Mapped shards keep the per-unit gathers: every die-local unit's stack
+    lands on its shard's pinned device, no batch gather program runs, and
+    the answers stay exact.  Four shard devices where the host has them,
+    else the one device four times (the same placed path)."""
+    from repro.flash import arena
+    from repro.flash.device import FlashDevice
+    from repro.flash.geometry import SSDConfig
+    devices = (jax.devices() * 4)[:4]
+    dev = FlashDevice(config=SSDConfig(page_kb=1), shard_devices=devices)
+    sess = ComputeSession(dev, backend=backend)
+    expr, ref = _random_dag(sess, np.random.default_rng(9), 4, 3000, "g")
+    units = []
+
+    def no_batch_program(*args, **kw):
+        raise AssertionError("batch gather program on the placed path")
+
+    monkeypatch.setattr(arena, "_gather_parts", no_batch_program)
+    stack = dev.vth_stack
+
+    def spied(wls, *, place=True):
+        out = stack(wls, place=place)
+        units.append((wls, place, out))
+        return out
+
+    monkeypatch.setattr(dev, "vth_stack", spied)
+    out = np.asarray(sess.materialize(expr, unpacked=True))
+    np.testing.assert_array_equal(out, ref)
+    assert sess.arena_gather_programs == sess.arena_gathered_stacks == 0
+    assert sess.placed_unit_dispatches == len(units) > 1
+    for wls, place, got in units:
+        (die,) = {dev.die_of_plane(wl[0]) for wl in wls}
+        assert place is False
+        assert got.devices() == {dev.arena.device_of(die)}

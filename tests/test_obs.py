@@ -166,7 +166,8 @@ def test_session_stats_keys_unchanged_and_attr_reads():
                       "arena_shards", "ledger",
                       "plans_verified", "verify_cache_hits", "verify",
                       "faults", "reliability",
-                      "placed_unit_dispatches", "host_drain",
+                      "placed_unit_dispatches", "arena_gather_programs",
+                      "arena_gathered_stacks", "host_drain",
                       "coalesced_sense_groups", "waves_shared",
                       "tail_mask_cache"}
     # pre-registry attribute reads still work and are plain ints
