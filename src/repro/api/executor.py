@@ -180,6 +180,10 @@ class ExecPlan:
     concurrent_dies: int          # max dies busy in one wave
     #: lowering-time placement writes (barrier wave -1), for hazard checking
     programs: List[ProgramStep] = dataclasses.field(default_factory=list)
+    #: wordlines whose arena rows were freed as this plan's lowering began
+    #: (barrier wave -1, :meth:`FlashDevice.begin_batch`): no unit may
+    #: sense them
+    freed: Tuple[WordlineKey, ...] = ()
     #: batch roots in request order (empty == single-root plan)
     roots: Tuple[int, ...] = ()
     roots_pages: Tuple[int, ...] = ()
@@ -698,10 +702,15 @@ class Executor:
                       rids: Optional[List[int]] = None):
         sess = self.session
         tracer = sess.trace
+        dev = sess.device
         # lowering (placement resolution) runs on the host wall clock; the
-        # FTL's realignment copybacks inside it also land as device spans
+        # FTL's realignment copybacks inside it also land as device spans.
+        # Rows retired before this batch are freed first, so this
+        # lowering's copybacks reuse them.
         with traced(tracer, "lower", "lower", roots=len(nodes)):
+            freed = dev.begin_batch()
             plan = _Lowering(sess).lower_many(nodes, rids)
+            plan.freed = tuple(freed)
         with traced(None, "executable"):
             sig = plan.signature(sess.backend.name)
             layout = self._placement_layout(plan)
@@ -742,7 +751,6 @@ class Executor:
             if tracer is not None and self.cache.evictions > evictions0:
                 tracer.instant("cache", "executable-evicted",
                                evicted=self.cache.evictions - evictions0)
-        dev = sess.device
         # The arena gathers run OUTSIDE the cached executable, so executable
         # input shapes depend only on the plan signature — shard growth must
         # not retrace cached executables.  Unplaced, every stack of the
@@ -847,6 +855,13 @@ class Executor:
         else:
             m.counter("arena_gather_programs").add(1)
             m.counter("arena_gathered_stacks").add(len(plan.groups) + n_fused)
+        copybacks = [pr for pr in plan.programs
+                     if pr.label.startswith("copyback")]
+        if copybacks:
+            m.counter("realignments").add(len(copybacks))
+            m.counter("copyback_wordlines").add(
+                sum(len(pr.wls) for pr in copybacks))
+        m.counter("arena_rows_reclaimed").add(len(plan.freed))
         m.counter("in_flash_senses").add(plan.senses)
         m.counter("sense_items").add(plan.items)
         m.counter("sense_batches").add(len(plan.groups) + n_fused)

@@ -61,6 +61,9 @@ _SESSION_COUNTERS = (
     ("coalesced_sense_groups", "batch sense groups shared by >1 request"),
     ("waves_shared", "schedule waves carrying work of >1 request"),
     ("tail_mask_evictions", "tail-mask cache entries evicted (LRU bound)"),
+    ("realignments", "copyback realignments while lowering batches"),
+    ("copyback_wordlines", "wordlines those copybacks programmed"),
+    ("arena_rows_reclaimed", "arena rows of retired wordlines freed"),
 )
 
 #: per-shape tail-mask cache bound — big enough for steady-state serving
@@ -480,6 +483,9 @@ class ComputeSession:
             "placed_unit_dispatches": self.placed_unit_dispatches,
             "arena_gather_programs": self.arena_gather_programs,
             "arena_gathered_stacks": self.arena_gathered_stacks,
+            "realignments": self.realignments,
+            "copyback_wordlines": self.copyback_wordlines,
+            "arena_rows_reclaimed": self.arena_rows_reclaimed,
             "host_drain": {"submits": self.host_drain_submits,
                            "blocks": self.host_drain_blocks,
                            "pending": len(self.host_queue),

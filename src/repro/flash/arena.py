@@ -126,6 +126,11 @@ class VthArena:
         return slots
 
     def free(self, slots: Sequence[int]) -> None:
+        """Return slots to the free list: the next allocations on this shard
+        reuse them (LIFO) before the buffer grows.  The device frees a row
+        once no live placement uses it, as the next batch's lowering begins
+        (:meth:`repro.flash.device.FlashDevice.begin_batch`); erasing a
+        block frees its rows at once."""
         for s in slots:
             self._row_encoding.pop(int(s), None)
         self._free.extend(int(s) for s in slots)
@@ -159,6 +164,12 @@ class VthArena:
         rows = jnp.asarray(rows, self.dtype).reshape(len(slots), self.page_bits)
         self._buf = _scatter_rows(self._buf, jnp.asarray(slots, jnp.int32),
                                   self._place(rows))
+
+    def install(self, buf: jnp.ndarray) -> None:
+        """Take an updated copy of the buffer, of the same shape, from a
+        program that wrote rows into it (a copyback realignment)."""
+        assert buf.shape == self._buf.shape, (buf.shape, self._buf.shape)
+        self._buf = buf
 
     def rows(self, slots: Sequence[int]) -> jnp.ndarray:
         """Row-index vector for a slot list (executable input)."""
@@ -224,6 +235,17 @@ class ShardedVthArena:
     def grows(self) -> int:
         return sum(s.grows for s in self._shards.values())
 
+    def used_on(self, die: int) -> int:
+        """Rows allocated on ``die``'s shard (0 where it has none)."""
+        shard = self._shards.get(die)
+        return shard.used if shard is not None else 0
+
+    def fits(self, die: int, n: int) -> bool:
+        """``n`` rows can be allocated on ``die`` without growing a buffer
+        in use: its shard has ``n`` free rows, or it has no shard yet."""
+        shard = self._shards.get(die)
+        return shard is None or shard.capacity - shard.used >= n
+
     def shard_stats(self) -> Dict[int, dict]:
         return {die: {"capacity": s.capacity, "used": s.used, "grows": s.grows,
                       "encodings": s.used_by_encoding()}
@@ -255,6 +277,8 @@ class ShardedVthArena:
         self.shard(int(die)).retag(slot, encoding)
 
     def free(self, refs: Sequence[SlotRef]) -> None:
+        """Return ``(die, slot)`` refs to their shards' free lists (see
+        :meth:`VthArena.free`)."""
         for die, slots in self._by_die(refs).items():
             self.shard(die).free(slots)
 

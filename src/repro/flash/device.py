@@ -26,10 +26,13 @@ whole schedule wave of per-die groups into ONE parallel ledger step.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+import functools
+import itertools
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.api.ledger import Ledger
 from repro.api.plan_cache import ExecutableCache, PlanCache
@@ -46,6 +49,75 @@ WordlineKey = Tuple[int, int, int]  # (plane, block, wordline)
 
 #: ledger/timing op label for a standard page read of each role
 PAGE_READ_OP = {"lsb": "and", "csb": "or", "msb": "or"}
+
+
+@functools.partial(jax.jit, static_argnames=("encoding", "chip", "n_pe",
+                                             "retention_hours"))
+def _program_vth(key: jax.Array, pages: Tuple[jnp.ndarray, ...], *,
+                 encoding: str, chip, n_pe: float,
+                 retention_hours: float) -> jnp.ndarray:
+    """Vth of one wordline programmed with its shared ``pages`` (role
+    order) under ``encoding``: the 4-state MLC chip, or the 8-state chip
+    for TLC and reduced MLC.  One jitted program, so a batch of wordlines
+    under ``vmap`` samples exactly the same values (:func:`_copyback_rows`)."""
+    if encoding == tlc.MLC:
+        lsb, msb = pages
+        return vth_model.program_page(key, lsb, msb, chip, n_pe=n_pe,
+                                      retention_hours=retention_hours)[0]
+    return tlc.program_tlc(key, tlc.encode_states(encoding, pages), chip,
+                           n_pe=n_pe)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "plans", "backend", "encoding", "chip", "n_pe"))
+def _copyback_rows(bufs: Tuple[jnp.ndarray, ...], idx: jnp.ndarray,
+                   key: jax.Array, *, plans: Tuple[ReadPlan, ...], backend,
+                   encoding: str, chip, n_pe: Tuple[Tuple[float, int], ...]):
+    """A copyback realignment of ``n`` wordlines as ONE program.
+
+    ``bufs`` holds the shard buffer of each of the ``K`` operands and then
+    the destination's (the same buffer repeated where dies coincide, so the
+    compile key does not depend on which do).  Operand ``k`` reads its
+    pages from rows ``idx[k*n:(k+1)*n]`` of ``bufs[k]`` with its page-read
+    plan ``plans[k]``; wordline ``j`` then programs page ``j`` of every
+    operand, in role order (roles past the operands hold zeros), into row
+    ``idx[K*n + j]`` of ``bufs[K]``, with the ``j``-th key drawn from
+    ``key`` as :meth:`FlashDevice._next_key` draws it, at the P/E count of
+    its block: ``n_pe`` gives ``(count, wordlines)`` runs in ``idx`` order.
+    Returns the updated destination buffer, the key after the draws and the
+    pages (uint8, role order): the rows a page read per source wordline and
+    one :meth:`FlashDevice.program_shared` per destination wordline give."""
+    from repro.kernels import ops as kops
+
+    n = idx.shape[0] // (len(plans) + 1)
+    pages = []
+    for k, plan in enumerate(plans):
+        vth = jnp.take(bufs[k], idx[k * n:(k + 1) * n], axis=0, mode="clip")
+        pages.append(kops.unpack_bits(backend.sense(vth, plan)))
+    pages += [jnp.zeros_like(pages[0])] * (PAGES_PER_WL[encoding] - len(pages))
+
+    def draw(k, _):
+        k, sub = jax.random.split(k)
+        return k, sub
+
+    key, subs = jax.lax.scan(draw, key, None, length=n)
+    vths, at = [], 0
+    for pe, count in n_pe:
+        program = functools.partial(_program_vth, encoding=encoding, chip=chip,
+                                    n_pe=pe, retention_hours=0.0)
+        vths.append(jax.vmap(lambda sub, *p: program(sub, p))(
+            subs[at:at + count], *(p[at:at + count] for p in pages)))
+        at += count
+    vth = vths[0] if len(vths) == 1 else jnp.concatenate(vths)
+    out = bufs[-1].at[idx[len(plans) * n:]].set(vth)
+    return out, key, tuple(pages)
+
+
+class _Rows(NamedTuple):
+    """Row ``row`` of a batch of pages in role order: the stored operands of
+    a wordline that a copyback programmed, kept without slicing."""
+    pages: Tuple[jnp.ndarray, ...]
+    row: int
 
 
 class FlashDevice:
@@ -72,7 +144,10 @@ class FlashDevice:
                                      devices=shard_devices)
         self._slot_of: Dict[WordlineKey, SlotRef] = {}
         # stored page bits per wordline, role order (2 for MLC/reduced, 3 TLC)
-        self._operands: Dict[WordlineKey, Tuple[jnp.ndarray, ...]] = {}
+        self._operands: Dict[WordlineKey, "Tuple[jnp.ndarray, ...] | _Rows"] = {}
+        #: retired wordlines: their rows are freed as the next batch's
+        #: lowering begins (:meth:`begin_batch`)
+        self._stale: List[WordlineKey] = []
         self._encoding_of: Dict[WordlineKey, str] = {}
         self.pe_counts: Dict[Tuple[int, int], int] = {}
         self.ledger = Ledger()
@@ -139,14 +214,16 @@ class FlashDevice:
                              msb_pages: List[jnp.ndarray],
                              retention_hours: float = 0.0, *,
                              csb_pages: "List[jnp.ndarray] | None" = None,
-                             encoding: str = tlc.MLC) -> None:
+                             encoding: str = tlc.MLC,
+                             kind: str = "program") -> None:
         """Program the shared pages of a wordline batch under one encoding.
 
         MLC programs (LSB, MSB) through the 4-state chip model; TLC programs
         (LSB, CSB, MSB) and reduced-MLC programs (LSB, MSB) on the widely
         spaced {L0, L2, L5, L7} states, both through the 8-state chip.  Vth
-        generation stays per-page (independent RNG streams), but the arena
-        write is ONE scatter and the ledger entry ONE batched call.
+        generation stays per-page (independent RNG streams, one jitted
+        :func:`_program_vth` each), but the arena write is ONE scatter and
+        the ledger entry ONE batched call, labelled ``kind``.
         """
         assert encoding in tlc.ENCODINGS, encoding
         if encoding == tlc.TLC:
@@ -157,27 +234,23 @@ class FlashDevice:
         assert len(wls) == len(lsb_pages) == len(msb_pages)
         if not wls:
             return
+        if encoding != tlc.MLC:
+            # retention drift is modeled for the MLC chip only; the §7
+            # experiments sweep P/E cycling
+            assert retention_hours == 0.0, \
+                "retention drift is not modeled for 8-state encodings"
+        chip = self.chip if encoding == tlc.MLC else self.tlc_chip
         vths = []
         for i, wl in enumerate(wls):
             lsb_bits, msb_bits = lsb_pages[i], msb_pages[i]
             assert lsb_bits.shape == (self._page_bits,), lsb_bits.shape
             plane, block, _ = wl
             n_pe = self.pe_counts.get((plane, block), 0)
-            if encoding == tlc.MLC:
-                vth, _ = vth_model.program_page(
-                    self._next_key(), lsb_bits, msb_bits, self.chip,
-                    n_pe=float(n_pe), retention_hours=retention_hours)
-                pages = (lsb_bits, msb_bits)
-            else:
-                # 8-state programming (retention drift is modeled for the
-                # MLC chip only; the §7 experiments sweep P/E cycling)
-                assert retention_hours == 0.0, \
-                    "retention drift is not modeled for 8-state encodings"
-                pages = ((lsb_bits, csb_pages[i], msb_bits)
-                         if encoding == tlc.TLC else (lsb_bits, msb_bits))
-                states = tlc.encode_states(encoding, pages)
-                vth = tlc.program_tlc(self._next_key(), states, self.tlc_chip,
-                                      n_pe=float(n_pe))
+            pages = ((lsb_bits, csb_pages[i], msb_bits)
+                     if encoding == tlc.TLC else (lsb_bits, msb_bits))
+            vth = _program_vth(self._next_key(), pages, encoding=encoding,
+                               chip=chip, n_pe=float(n_pe),
+                               retention_hours=float(retention_hours))
             if self.faults is not None:
                 vth = self.faults.perturb(vth, plane=plane, block=block,
                                           wl=wl[2], n_pe=n_pe)
@@ -197,20 +270,24 @@ class FlashDevice:
                 self.arena.retag(slot, encoding)
             slots.append(slot)
         self.arena.write(slots, jnp.stack(vths))
-        # shared-page program: one page's worth of ISPP per shared page
+        self._book_program(wls, encoding, kind)
+
+    def _book_program(self, wls: List[WordlineKey], encoding: str,
+                      kind: str) -> None:
+        """Ledger (and plan program log) entry of a shared-page program of
+        ``wls``: one page's worth of ISPP per shared page."""
         n_pages = PAGES_PER_WL[encoding]
         per_die: Dict[int, float] = {}
         for wl in wls:
             die = self.die_of_plane(wl[0])
             per_die[die] = per_die.get(die, 0.0) + n_pages * self.timing.t_prog_us
+        label = f"{kind} {encoding}x{len(wls)}p"
         self.ledger.add_die_batch(
             per_die,
             n_pages * self.energy.e_prog_uj_kb * self.config.page_kb * len(wls),
-            commands=len(wls), category="program",
-            label=f"program {encoding}x{len(wls)}p")
+            commands=len(wls), category="program", label=label)
         if self.program_log is not None:
-            self.program_log.append((f"program {encoding}x{len(wls)}p",
-                                     list(wls)))
+            self.program_log.append((label, list(wls)))
 
     def program_shared(self, wl: WordlineKey, lsb_bits: jnp.ndarray,
                        msb_bits: jnp.ndarray, retention_hours: float = 0.0,
@@ -343,17 +420,93 @@ class FlashDevice:
                                    encoding=encoding)
         return out[0] if packed else kops.unpack_bits(out)[0]
 
-    def copyback_align(self, src_a: WordlineKey, src_b: WordlineKey,
-                       dst: WordlineKey, which_a: str = "lsb",
-                       which_b: str = "lsb", *, backend=None) -> None:
-        """Realign two scattered operands onto one shared wordline (Fig 9e).
+    def copyback(self, srcs: Sequence[List[WordlineKey]],
+                 roles: Sequence[str], dst: List[WordlineKey],
+                 encoding: str = tlc.MLC) -> None:
+        """Copyback realignment (Fig 9e): read role ``roles[k]`` of every
+        source wordline ``srcs[k][j]`` and program those pages together, in
+        the encoding's role order, onto the fresh wordline ``dst[j]``.
 
-        Uses the on-die cache register (no external transfer): two page reads
-        + one shared-page copyback program.
+        ONE jitted program (:func:`_copyback_rows`) for all the wordlines:
+        the same page reads, the same Vth sampling and the same keys as a
+        page read per source and a :meth:`program_shared` per destination
+        wordline, so the rows are bit-identical to that, at the P/E count
+        of each destination block; the ledger books those reads and that
+        program.  With a fault model installed (its perturbation is per
+        row) or shards on several JAX devices, the pages are read in one
+        sense per operand and programmed by :meth:`program_shared_batch`
+        instead: the benchmark's control (worn, faulty blocks) runs that
+        path, not :func:`_copyback_rows`.
         """
-        a = self.page_read(src_a, which_a, packed=False, backend=backend)
-        b = self.page_read(src_b, which_b, packed=False, backend=backend)
-        self.program_shared(dst, a, b)
+        from repro.kernels import ops as kops
+
+        n = len(dst)
+        assert n and all(len(s) == n for s in srcs), (n, [len(s) for s in srcs])
+        if self.faults is not None or self.arena.devices:
+            rows = [kops.unpack_bits(self.page_read_batch(s, r,
+                                                          encoding=encoding))
+                    for s, r in zip(srcs, roles)]
+            zeros = jnp.zeros_like(rows[0])
+            by_role = dict(zip(tlc.ROLES_OF[encoding], rows))
+            pages = {role: list(by_role.get(role, zeros))
+                     for role in tlc.ROLES_OF[encoding]}
+            self.program_shared_batch(dst, pages["lsb"], pages["msb"],
+                                      csb_pages=pages.get("csb"),
+                                      encoding=encoding, kind="copyback")
+            return
+        plans = tuple(self.page_read_plan(r, encoding) for r in roles)
+        for s, r, plan in zip(srcs, roles, plans):
+            self.account_page_read_batch(s, r, phases=plan.sensing_phases)
+        die = self.die_of_plane(dst[0][0])
+        assert all(self.die_of_plane(p) == die for p, _, _ in dst), dst
+        assert not any(wl in self._slot_of for wl in dst), \
+            "copyback programs fresh wordlines"
+        refs = self.arena.alloc(die, n, encoding=encoding)
+        dies = [self.die_of_plane(s[0][0]) for s in srcs] + [die]
+        idx = ([self._slot_of[wl][1] for s in srcs for wl in s]
+               + [slot for _, slot in refs])
+        buf, self._key, pages = _copyback_rows(
+            tuple(self.arena.shard(d).buf for d in dies),
+            np.asarray(idx, np.int32), self._key, plans=plans,
+            backend=self._default_backend, encoding=encoding,
+            chip=self.chip if encoding == tlc.MLC else self.tlc_chip,
+            n_pe=tuple((float(pe), len(list(run))) for pe, run in
+                       itertools.groupby(self.pe_counts.get((p, b), 0)
+                                         for p, b, _ in dst)))
+        self.arena.shard(die).install(buf)
+        for j, (wl, ref) in enumerate(zip(dst, refs)):
+            self._slot_of[wl] = ref
+            self._operands[wl] = _Rows(pages, j)
+            self._encoding_of[wl] = encoding
+        self._book_program(dst, encoding, "copyback")
+
+    # -- reclamation of retired rows -----------------------------------------
+    def retire(self, wls: List[WordlineKey]) -> None:
+        """No live placement uses ``wls`` any more (their vectors moved or
+        were rewritten).  Their rows are freed as the next batch's lowering
+        begins (:meth:`begin_batch`), not at once: the lowering under way
+        may have planned a sense of them."""
+        self._stale.extend(wls)
+
+    def begin_batch(self) -> List[WordlineKey]:
+        """A batch's lowering begins: frees the rows of every wordline
+        retired before it, so the lowering's copybacks reuse them; returns
+        those wordlines.  A batch dispatched earlier is not disturbed: arena
+        updates write new buffers (nothing is donated), and its gather
+        already holds the old ones."""
+        freed: List[WordlineKey] = []
+        refs: List[SlotRef] = []
+        for wl in self._stale:
+            ref = self._slot_of.pop(wl, None)
+            if ref is None:              # its block was erased meanwhile
+                continue
+            refs.append(ref)
+            freed.append(wl)
+            self._operands.pop(wl, None)
+            self._encoding_of.pop(wl, None)
+        self._stale.clear()
+        self.arena.free(refs)
+        return freed
 
     def erase_block(self, plane: int, block: int) -> None:
         self.pe_counts[(plane, block)] = self.pe_counts.get((plane, block), 0) + 1
@@ -397,14 +550,17 @@ class FlashDevice:
     # -- oracles for verification -------------------------------------------
     def stored_operands(self, wl: WordlineKey) -> Tuple[jnp.ndarray, ...]:
         """Stored page bits in role order (2 pages for MLC/reduced, 3 TLC)."""
-        return self._operands[wl]
+        pages = self._operands[wl]
+        if isinstance(pages, _Rows):
+            return tuple(p[pages.row] for p in pages.pages)
+        return pages
 
     def encoding_of(self, wl: WordlineKey) -> str:
         """Row encoding of a programmed wordline."""
         return self._encoding_of[wl]
 
     def expected(self, wl: WordlineKey, op: str) -> jnp.ndarray:
-        pages = self._operands[wl]
+        pages = self.stored_operands(wl)
         assert len(pages) == 2, \
             "expected() models 2-operand wordlines; 3-page TLC wordlines " \
             "need a 3-operand oracle (see tests/test_cross_encoding.py)"

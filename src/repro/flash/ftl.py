@@ -18,8 +18,12 @@ as a read with a per-op SET_FEATURE offset set.  This module provides:
   single-sense fast paths,
 - aligned operand-group writes (operands assigned shared-page roles in
   canonical order on the same wordlines),
-- runtime copyback realignment for scattered operands (realigned and
-  NOT-ready derived placements inherit the source vector's home die).
+- runtime copyback realignment for scattered operands, one device program
+  per realignment (a realigned group goes to an operand's home die where
+  its shard has room, else to the least-loaded die with room; NOT-ready
+  derived placements inherit the source vector's home die),
+- reclamation: a placement no vector lives on any more is retired to the
+  device, which frees its arena rows once no batch can still read them.
 
 Vector-level *compute* lives in :class:`repro.api.ComputeSession`; the
 historical ``mcflash_compute`` / ``mcflash_chain`` entry points remain as
@@ -74,6 +78,9 @@ class FTL:
         #: name -> ordered tuple of ALL names co-located on its wordlines
         #: (pairs under MLC/reduced-MLC, up to triples under TLC)
         self._group_of: Dict[str, Tuple[str, ...]] = {}
+        #: live vectors per placement, keyed by its first wordline (the
+        #: members of a co-located group share one placement)
+        self._users: Dict[WordlineKey, int] = {}
         self._next_die = 0                               # round-robin home die
         self._session = None
 
@@ -130,6 +137,25 @@ class FTL:
         return [self.allocate_wordline(die * ppd + (i % ppd))
                 for i in range(n_pages)]
 
+    def _realign_die(self, metas: Sequence[VectorMeta]) -> int:
+        """Home die of a realigned group: the first operand's home die whose
+        arena shard has room for the group's rows; else the die with the
+        fewest rows among those with room (or no shard yet); else, with no
+        room anywhere, the operands' die with the fewest rows.
+
+        Realignment then grows no shard while the SSD has room.  A vector
+        ANDed with one partner after another (a year bitmap with each
+        discount in turn) would otherwise pile the merged placements onto
+        one die, whose shard grows, and recompiles every program that
+        reads it, at a point that depends on the order of the queries."""
+        arena, n = self.device.arena, len(metas[0].pages)
+        dies = list(dict.fromkeys(m.die for m in metas))
+        for die in dies:
+            if arena.fits(die, n):
+                return die
+        roomy = [d for d in range(self.cfg.dies) if arena.fits(d, n)]
+        return min(roomy or dies, key=arena.used_on)
+
     def die_of(self, name: str) -> int:
         """Home die of a registered vector."""
         return self.vectors[name].die
@@ -155,10 +181,35 @@ class FTL:
         """Name of the NOT-ready derived placement the session may cache."""
         return f"__not__{name}"
 
+    def _register(self, meta: VectorMeta) -> None:
+        """Make ``meta`` the live placement of its vector."""
+        self.vectors[meta.name] = meta
+        if meta.pages:
+            key = meta.pages[0]
+            self._users[key] = self._users.get(key, 0) + 1
+
+    def _drop(self, name: str) -> None:
+        """Forget a vector's placement; the last vector to leave a placement
+        retires its wordlines (:meth:`FlashDevice.retire`): their rows are
+        freed as the next batch's lowering begins.  A placement whose
+        partner still lives there stays."""
+        meta = self.vectors.pop(name, None)
+        if meta is None or not meta.pages:
+            return
+        key = meta.pages[0]
+        left = self._users[key] - 1
+        if left:
+            self._users[key] = left
+        else:
+            del self._users[key]
+            self.device.retire(meta.pages)
+
     def _invalidate(self, name: str) -> None:
-        """Rewriting a vector drops it from its co-location group (remaining
-        members still share THEIR wordlines) and drops any derived placements
-        built from its old contents."""
+        """Before a vector is rewritten or moved: drop it from its
+        co-location group (remaining members still share THEIR wordlines),
+        drop any derived placement built from its old contents, and drop
+        its placement (:meth:`_drop`, which retires the wordlines once no
+        vector lives there)."""
         group = self._group_of.pop(name, None)
         if group is not None:
             rest = tuple(n for n in group if n != name)
@@ -167,7 +218,8 @@ class FTL:
                     self._group_of[n] = rest
                 else:
                     self._group_of.pop(n, None)
-        self.vectors.pop(self.derived_not_name(name), None)
+        self._drop(self.derived_not_name(name))
+        self._drop(name)
 
     def _checkword(self, bits, n_bits: int) -> np.ndarray:
         """Sampled-parity checkword of a vector being written (positions are
@@ -234,10 +286,10 @@ class FTL:
         self._program_roles(placement,
                             dict(zip(roles, paged)), encoding)
         for name, b, role in zip(names, bits, roles):
-            self.vectors[name] = VectorMeta(name, int(b.shape[0]), placement,
-                                            role, die=die, encoding=encoding,
-                                            check=self._checkword(
-                                                b, int(b.shape[0])))
+            self._register(VectorMeta(name, int(b.shape[0]), placement, role,
+                                      die=die, encoding=encoding,
+                                      check=self._checkword(
+                                          b, int(b.shape[0]))))
             self._group_of[name] = tuple(names)
 
     def write_pair_aligned(self, name_a: str, bits_a: jnp.ndarray,
@@ -260,50 +312,58 @@ class FTL:
         die = self._home_die(die)
         placement = self._placement(len(pages), die)
         self._program_roles(placement, {role: pages}, encoding)
-        self.vectors[name] = VectorMeta(name, int(bits.shape[0]), placement,
-                                        role, zero_co_page=True, die=die,
-                                        encoding=encoding,
-                                        check=self._checkword(
-                                            bits, int(bits.shape[0])))
+        self._register(VectorMeta(name, int(bits.shape[0]), placement, role,
+                                  zero_co_page=True, die=die,
+                                  encoding=encoding,
+                                  check=self._checkword(
+                                      bits, int(bits.shape[0]))))
 
     def align(self, name_a: str, name_b: str) -> str:
-        """Copyback-realign two scattered MLC vectors into an aligned pair;
-        returns the name of the merged pair (A becomes LSB, B becomes MSB).
-        The merged pair lives on A's home die (die affinity is preserved)."""
+        """Copyback-realign two MLC vectors onto shared wordlines; returns
+        the name of the merged pair (A becomes LSB, B becomes MSB).  The
+        merged pair lives on A's home die, or B's, where its shard has room
+        (:meth:`_realign_die`).
+
+        The copy is ONE device program for all the wordlines
+        (:meth:`FlashDevice.copyback`), a ``repro.ftl`` span.  Both old
+        placements are dropped: one that no vector lives on any more is
+        retired, and its arena rows are freed as the next batch's lowering
+        begins (:meth:`FlashDevice.begin_batch`); one whose partner still
+        lives there stays until the partner moves."""
         ma, mb = self.vectors[name_a], self.vectors[name_b]
         assert ma.encoding == mb.encoding == tlc.MLC, \
             "align() is the MLC copyback path; use align_group for " \
             "encoded vectors"
         assert len(ma.pages) == len(mb.pages)
+        die = self._realign_die([ma, mb])
         self._invalidate(name_a)
         self._invalidate(name_b)
-        placement = []
+        placement = self._placement(len(ma.pages), die)
         with traced(self._tracer, "ftl", f"copyback-align[{name_a},{name_b}]",
-                    pages=len(ma.pages)):
-            for wa, wb in zip(ma.pages, mb.pages):
-                dst = self.allocate_wordline(wa[0])
-                self.device.copyback_align(wa, wb, dst, ma.role, mb.role)
-                placement.append(dst)
+                    pages=len(placement)):
+            self.device.copyback([ma.pages, mb.pages], [ma.role, mb.role],
+                                 placement)
         # the copyback preserves data, so the checkwords carry over
-        self.vectors[name_a] = VectorMeta(name_a, ma.n_bits, placement, "lsb",
-                                          die=ma.die, check=ma.check)
-        self.vectors[name_b] = VectorMeta(name_b, mb.n_bits, placement, "msb",
-                                          die=ma.die, check=mb.check)
+        self._register(VectorMeta(name_a, ma.n_bits, placement, "lsb",
+                                  die=die, check=ma.check))
+        self._register(VectorMeta(name_b, mb.n_bits, placement, "msb",
+                                  die=die, check=mb.check))
         self._group_of[name_a] = self._group_of[name_b] = (name_a, name_b)
         return name_a
 
     def align_group(self, names: Sequence[str]) -> None:
         """Copyback-realign k same-encoding vectors onto shared wordlines
-        (the generalized multi-level-encoding realignment): each operand's
-        pages are read out on-die and the group reprograms together on the
-        first vector's home die, taking shared-page roles in canonical
-        order.  MLC pairs keep the classic two-read copyback path."""
-        from repro.kernels import ops as kops
-
+        (the generalized multi-level-encoding realignment): the group
+        reprograms together on the home die :meth:`_realign_die` picks,
+        taking shared-page roles in canonical order, in one device program
+        (:meth:`FlashDevice.copyback`); old placements retire as in
+        :meth:`align`.  MLC pairs take :meth:`align`."""
         metas = [self.vectors[n] for n in names]
         enc = metas[0].encoding
         assert all(m.encoding == enc for m in metas), \
             f"cannot co-locate mixed encodings: {[m.encoding for m in metas]}"
+        assert len({len(m.pages) for m in metas}) == 1, \
+            "aligned operands must match in size"
         if enc == tlc.MLC and len(names) == 2:
             self.align(names[0], names[1])
             return
@@ -314,19 +374,24 @@ class FTL:
         # path instead.
         mgr = getattr(self._session, "reliability", None) \
             if self._session is not None else None
+        die = self._realign_die(metas)
         with traced(self._tracer, "ftl",
                     f"align-group[{','.join(names)}]", encoding=enc):
-            bits = []
-            for m in metas:
-                if mgr is not None:
-                    bits.append(jnp.asarray(mgr.read_vector_checked(m)))
-                    continue
-                packed = self.device.page_read_batch(m.pages, m.role,
-                                                     encoding=enc)
-                bits.append(
-                    kops.unpack_bits(packed.reshape(1, -1))[0][: m.n_bits])
-            self.write_group_aligned(list(names), bits, die=metas[0].die,
-                                     encoding=enc)
+            if mgr is not None:
+                bits = [jnp.asarray(mgr.read_vector_checked(m)) for m in metas]
+                self.write_group_aligned(list(names), bits, die=die,
+                                         encoding=enc)
+                return
+            for n in names:
+                self._invalidate(n)
+            placement = self._placement(len(metas[0].pages), die)
+            self.device.copyback([m.pages for m in metas],
+                                 [m.role for m in metas], placement, enc)
+        for m, role in zip(metas, ROLES_OF[enc]):
+            self._register(VectorMeta(m.name, m.n_bits, placement, role,
+                                      die=die, encoding=enc,
+                                      check=m.check))
+            self._group_of[m.name] = tuple(names)
 
     # -- executor lowering helpers --------------------------------------------
     def group_for_sense(self, names: List[str]) -> Tuple[List[Tuple[str, ...]], "str | None"]:

@@ -29,6 +29,16 @@ batches preempts the score order entirely (it MUST ship in the next batch).
 oldest request past the bound — and admission past ``max_queue_depth``
 auto-dispatches to bound queue memory.
 
+**Streaming a long queue.**  Lowering a batch is host work, and the host
+is one thread: a queue of many batches lowered back to back would hold
+every answer, the first batch's too, until the last batch is lowered.  So
+:meth:`~QueryEngine.poll` dispatches nothing while ``lookahead_batches``
+dispatched batches still hold a ticket whose result has not been taken,
+and :meth:`QueryTicket.result` dispatches the next full batch before it
+blocks: the device always has the next batch to run while the caller
+collects the answers of the one before, and answers come back batch by
+batch.
+
 **Observability.**  Under ``jax.profiler.start_trace`` or the profiler
 server, each :meth:`~QueryEngine.step` is a ``repro.serve.step`` span on the
 clock of the device's ``XLA Ops``, split into ``repro.lower``,
@@ -74,6 +84,9 @@ class SLOConfig:
     aging_weight: float = 1.0
     #: admission bound: submitting past this queue depth auto-dispatches
     max_queue_depth: int = 64
+    #: dispatched batches with results not yet taken past which
+    #: :meth:`QueryEngine.poll` holds the queue (answers stream back)
+    lookahead_batches: int = 2
 
     def __post_init__(self):
         if self.max_batch_requests < 1:
@@ -84,6 +97,9 @@ class SLOConfig:
                              f"got {self.max_wait_batches}")
         if self.max_queue_depth < self.max_batch_requests:
             raise ValueError("max_queue_depth must hold at least one batch")
+        if self.lookahead_batches < 1:
+            raise ValueError(f"lookahead_batches must be >= 1, "
+                             f"got {self.lookahead_batches}")
 
 
 class QueryTicket:
@@ -122,10 +138,12 @@ class QueryTicket:
 
     def result(self):
         """Block for this request's result.  Dispatches the pending queue
-        first if this ticket is still waiting in admission."""
+        first if this ticket is still waiting in admission, and the next
+        full batch before it blocks (:meth:`QueryEngine.feed`)."""
         if self._result is None:
             while self._handle is None:
                 self._engine.step()
+            self._engine.feed()
             out = self._handle.result()
             self._result = int(np.asarray(out).reshape(-1)[0]) \
                 if self.popcount else out
@@ -143,6 +161,8 @@ class QueryEngine:
         self._queue: List[QueryTicket] = []    # admission order
         self._next_rid = 0
         self._batches = 0
+        #: batch index -> its tickets whose result has not been taken
+        self._untaken: Dict[int, int] = {}
         self._epoch = time.perf_counter()
         #: serving-layer typed metrics (the session keeps its own registry
         #: with the coalescing counters; stats() merges both views)
@@ -239,6 +259,7 @@ class QueryEngine:
                 t._handle = h
                 t.batch = bi
                 t._expr = None                 # the DAG is lowered; drop it
+            self._untaken[bi] = len(batch)
             self.metrics.counter("batches_dispatched").add(1)
             self.metrics.histogram("batch_requests").observe(len(batch))
             self.metrics.gauge("queue_depth").set(len(self._queue))
@@ -247,9 +268,12 @@ class QueryEngine:
     def poll(self) -> int:
         """Dispatch a (possibly partial) batch only when the SLO demands
         it: the queue holds a full batch, or the oldest pending request has
-        aged past ``max_delay_us``.  The arrival loop calls this after each
-        submit; an empty return means the batch former is still waiting."""
-        if not self._queue:
+        aged past ``max_delay_us``; and nothing while ``lookahead_batches``
+        dispatched batches hold results not yet taken.  The arrival loop
+        calls this after each submit; an empty return means the batch
+        former is still waiting."""
+        if not self._queue or \
+                len(self._untaken) >= self.slo.lookahead_batches:
             return 0
         if len(self._queue) >= self.slo.max_batch_requests:
             return self.step()
@@ -259,8 +283,23 @@ class QueryEngine:
             return self.step()
         return 0
 
+    def feed(self) -> int:
+        """Dispatch one full batch if the queue holds one and fewer than
+        ``lookahead_batches`` dispatched batches hold results not yet
+        taken; :meth:`QueryTicket.result` calls this before it blocks, so
+        the device runs the next batch while the caller takes answers."""
+        if len(self._queue) < self.slo.max_batch_requests or \
+                len(self._untaken) >= self.slo.lookahead_batches:
+            return 0
+        return self.step()
+
     # -- completion ----------------------------------------------------------
     def _completed(self, ticket: QueryTicket) -> None:
+        left = self._untaken[ticket.batch] - 1
+        if left:
+            self._untaken[ticket.batch] = left
+        else:
+            del self._untaken[ticket.batch]
         self.metrics.counter("requests_completed").add(1)
         tracer = self.session.trace
         if tracer is not None:

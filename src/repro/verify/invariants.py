@@ -14,7 +14,8 @@ scheduling is caught before a single kernel dispatches, not by sampling:
   ``(die, slot)`` wordline must be separated by a wave barrier, and no two
   units in one wave may strobe the same wordline: a race detector for the
   schedule.  Placement writes performed during lowering occupy the implicit
-  pre-dispatch barrier wave ``-1``.
+  pre-dispatch barrier wave ``-1``, and so do the frees of retired rows as
+  lowering begins: no unit may sense a wordline whose row was freed.
 - ``schedule-topology`` — every combine's inputs are produced at a strictly
   earlier schedule position, every partial is produced exactly once, and the
   root is produced.
@@ -320,6 +321,13 @@ def check_slot_hazards(plan, ctx: PlanContext) -> None:
                         die=ctx.die_of_plane(wl[0]))
                 owner[wl] = unit
                 sense_waves.setdefault(wl, []).append((wi, unit))
+    for wl in getattr(plan, "freed", ()):
+        for wi, unit in sense_waves.get(wl, ()):
+            raise PlanInvariantError(
+                "slot-hazard",
+                f"{unit} senses wordline {wl} whose arena row was freed at"
+                " the pre-dispatch barrier (wave -1) — a read after free",
+                plan=plan, wave=wi, unit=unit, die=ctx.die_of_plane(wl[0]))
     for pi, pr in enumerate(getattr(plan, "programs", []) or []):
         for wl in pr.wls:
             for wi, unit in sense_waves.get(wl, ()):

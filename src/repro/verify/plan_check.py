@@ -34,7 +34,9 @@ class PlanVerifier:
 
     ``mode`` is one of ``"off"`` / ``"on"`` / ``"paranoid"``.  A plan whose
     signature already verified clean is skipped (counted as a memo hit) —
-    except in paranoid mode, which re-checks every time.
+    except in paranoid mode, which re-checks every time, and for a plan
+    whose lowering freed rows (``plan.freed``), which the signature does not
+    hold.
     """
 
     def __init__(self, mode: str = "on"):
@@ -57,7 +59,7 @@ class PlanVerifier:
         if self.mode == "off":
             return
         if (signature is not None and self.mode != "paranoid"
-                and signature in self._clean):
+                and not getattr(plan, "freed", ()) and signature in self._clean):
             self.cache_hits += 1
             return
         if self.mode == "paranoid" and not ctx.paranoid:

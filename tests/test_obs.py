@@ -167,7 +167,9 @@ def test_session_stats_keys_unchanged_and_attr_reads():
                       "plans_verified", "verify_cache_hits", "verify",
                       "faults", "reliability",
                       "placed_unit_dispatches", "arena_gather_programs",
-                      "arena_gathered_stacks", "host_drain",
+                      "arena_gathered_stacks", "realignments",
+                      "copyback_wordlines", "arena_rows_reclaimed",
+                      "host_drain",
                       "coalesced_sense_groups", "waves_shared",
                       "tail_mask_cache"}
     # pre-registry attribute reads still work and are plain ints

@@ -190,6 +190,29 @@ def test_queue_depth_bound_auto_dispatches():
     _resolve(t1, oracles[1])
 
 
+def test_answers_stream_back_batch_by_batch():
+    """A queue of 4 full batches: poll dispatches 2 and holds the rest
+    while their answers are untaken; each batch's first result() dispatches
+    the next batch before it blocks, so every batch but the last goes out
+    while the one before it is collected, and every answer is exact."""
+    sess = _session("sim")
+    rng = np.random.default_rng(8)
+    exprs, pcs, oracles = _workload(sess, rng, n_requests=8)
+    eng = QueryEngine(sess, SLOConfig(max_batch_requests=2,
+                                      max_delay_us=0.0))
+    tickets = [eng.submit(e, popcount=pc) for e, pc in zip(exprs, pcs)]
+    while eng.poll():
+        pass
+    assert [t.dispatched for t in tickets] == [True] * 4 + [False] * 4
+    dispatched = []
+    for t, oracle in zip(tickets, oracles):
+        _resolve(t, oracle)
+        dispatched.append(sum(u.dispatched for u in tickets))
+    assert dispatched == [4, 4, 6, 6, 8, 8, 8, 8]
+    assert eng.stats()["batches_dispatched"] == 4
+    assert eng.poll() == 0 and not eng._untaken
+
+
 def test_slo_config_validation():
     with pytest.raises(ValueError, match="max_batch_requests"):
         SLOConfig(max_batch_requests=0)
@@ -197,6 +220,8 @@ def test_slo_config_validation():
         SLOConfig(max_wait_batches=0)
     with pytest.raises(ValueError, match="max_queue_depth"):
         SLOConfig(max_batch_requests=8, max_queue_depth=4)
+    with pytest.raises(ValueError, match="lookahead_batches"):
+        SLOConfig(lookahead_batches=0)
 
 
 # ------------------------- trace attribution --------------------------------

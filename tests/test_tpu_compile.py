@@ -93,3 +93,26 @@ def test_popcount_rows_compiles(one_chip):
         lambda w: popcount_rows(w, interpret=False),
         _spec(one_chip, (PAGES, WORDS), jnp.uint32))
     assert "tpu_custom_call" in text
+
+
+def test_copyback_rows_compiles(one_chip, monkeypatch):
+    """One realignment of a 92-page bitmap pair (TPC-H Q6 at 12M rows) as
+    one program, from two 512-row die shards into a third."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # compiled
+    from repro.api.backends import PallasBackend
+    from repro.core.mcflash import ReadPlan
+    from repro.core.vth_model import get_chip_model
+    from repro.flash.device import _copyback_rows
+
+    chip = get_chip_model()
+    v0, v1, v2 = chip.vref_default
+    plans = (ReadPlan("page_lsb", "lsb", (v1,), 1),
+             ReadPlan("page_msb", "msb", (v0, v2), 2))
+    n = 92
+    shard = _spec(one_chip, (512, PAGE_CELLS), jnp.float32)
+    text = _copyback_rows.lower(
+        (shard, shard, shard), _spec(one_chip, (3 * n,), jnp.int32),
+        _spec(one_chip, (2,), jnp.uint32), plans=plans,
+        backend=PallasBackend(), encoding="mlc", chip=chip,
+        n_pe=((0.0, n),)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2          # one sense per operand
